@@ -1,8 +1,8 @@
 """Paper Fig 5/7/8: throughput (QPS) vs recall, BANG vs brute-force baseline,
 plus the mesh-sharded serving sweep (the billion-scale regime's shape).
 
-CPU host stands in for the accelerator (numbers are relative, the shape of
-the QPS/recall frontier is the reproduced object). Four sweeps:
+Every timing is taken on whatever backend JAX runs on; a CPU or
+interpret-mode timing is not a device number. Four sweeps:
 
   * **Kernel-mode sweep** (single device): the serving workload under each
     traversal-step implementation -- "fused" search_step megakernel vs
@@ -15,11 +15,10 @@ the QPS/recall frontier is the reproduced object). Four sweeps:
     does to trace the QPS/recall curve; the brute-force scan is the exact
     baseline every ANNS must beat.
   * **Model-axis device sweep** (sharded + sharded-base): the same serving
-    workload on 1/2/4/8 fake host devices
-    (`XLA_FLAGS=--xla_force_host_platform_device_count`, one subprocess per
-    count because the device count locks at backend init), index state
-    sharded over the `model` axis via `ShardedSearchExecutor` -- every added
-    device grows the servable graph. Run for both graph placements: device
+    workload on meshes of the first 1/2/4/8 devices that exist, all in this
+    process, index state sharded over the `model` axis via
+    `ShardedSearchExecutor` -- every added device grows the servable graph.
+    Run for both graph placements: device
     HBM (`variant="sharded"`) and host RAM behind per-shard callbacks
     (`variant="sharded-base"`).
   * **Data-axis sweep** (query-parallel scaling): the same devices all on
@@ -39,9 +38,6 @@ derived column so the benchmark trajectory measures search, not tracing.
 from __future__ import annotations
 
 import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 
@@ -185,83 +181,53 @@ def _worklist_sweep(report) -> None:
 
 
 def _device_sweep(report) -> None:
-    """One subprocess per forced device count (jax locks it at backend init)."""
-    for devices in SHARDED_DEVICE_COUNTS:
-        env = dict(os.environ)
-        # Append (not overwrite): user XLA tuning flags must apply to both
-        # sweeps or the device-scaling comparison is skewed.
-        env["XLA_FLAGS"] = (
-            env.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={devices}"
-        ).strip()
-        env["PYTHONPATH"] = env.get("PYTHONPATH", "src") or "src"
-        try:
-            out = subprocess.run(
-                [sys.executable, "-m", "benchmarks.bench_qps_recall",
-                 "--sharded-worker", str(devices)],
-                env=env, capture_output=True, text=True, timeout=1800,
-            )
-        except subprocess.TimeoutExpired:
-            report(f"fig9_sharded_d{devices}", 0.0, "error=worker timeout")
-            continue
-        # Rows flush as each cell completes: report whatever finished even if
-        # a later cell of the same subprocess crashed, then the error.
-        for line in out.stdout.splitlines():
-            if line.startswith("ROWJSON,"):
-                row = json.loads(line.split(",", 1)[1])
-                report(row["name"], row["us_per_query"], _row_derived(row))
-        if out.returncode != 0:
-            err_lines = (out.stderr or "").strip().splitlines()
-            err = err_lines[-1][:80] if err_lines else "unknown"
-            report(f"fig9_sharded_worker_d{devices}", 0.0, f"error={err}")
+    """Serve the bench workload on meshes of the first k real devices.
 
+    One process drives every mesh (a chip belongs to one process), for each
+    k in SHARDED_DEVICE_COUNTS that exists. Emits one row per cell:
 
-def _sharded_worker(devices: int) -> None:
-    """Child process body: serve the bench workload on forced-device meshes.
-
-    Emits one `ROWJSON,<record>` line per (mesh, variant) cell:
-
-      fig9_sharded_d{N}        model-axis mesh (1, N), graph device-sharded
-      fig9_sharded_base_d{N}   model-axis mesh (1, N), graph in host RAM
+      fig9_sharded_d{k}        model-axis mesh (1, k), graph device-sharded
+      fig9_sharded_base_d{k}   model-axis mesh (1, k), graph in host RAM
                                behind per-shard callbacks (host-link traffic)
-      fig9_dataparallel_d{N}   data-axis mesh (N, 1), graph replicated,
-                               queries split N ways (query-parallel scaling)
+      fig9_dataparallel_d{k}   data-axis mesh (k, 1), graph replicated,
+                               queries split k ways (query-parallel scaling)
+
+    A CPU rehearsal gets several devices from the caller's
+    XLA_FLAGS=--xla_force_host_platform_device_count=N.
     """
     import jax
 
     from repro.compat import make_mesh
     from repro.runtime import ShardedSearchExecutor
 
-    assert len(jax.devices()) == devices, jax.devices()
     data, queries, idx = bench_dataset()
     k = 10
     gt = brute_force_knn(data, queries, k)
     cfg = SearchConfig(t=SHARDED_T, bloom_z=16384)
-    cells = [
-        # All devices on `model`: every added device grows the servable
-        # graph -- the capability the model-axis sweep exists to measure.
-        (f"fig9_sharded_d{devices}", (1, devices), "sharded"),
-        (f"fig9_sharded_base_d{devices}", (1, devices), "sharded-base"),
-    ]
-    if devices > 1:
-        # All devices on `data`: the query-parallel scaling curve. At
-        # devices=1 this cell would duplicate fig9_sharded_d1 exactly.
-        cells.append((f"fig9_dataparallel_d{devices}", (devices, 1), "sharded"))
-    for name, mesh_shape, variant in cells:
-        mesh = make_mesh(mesh_shape, ("data", "model"))
-        ex = ShardedSearchExecutor.from_index(idx, mesh, variant=variant)
-        pipe = ServePipeline(ex, k=k, cfg=cfg, max_batch=SHARDED_BATCH)
-        r, best_qps, best_wall, warm = _steady_state(pipe, queries, gt)
-        row = sharded_row(
-            name, ex, devices, r, best_qps,
-            best_wall / len(queries) * 1e6, warm.compile_s,
-        )
-        print(f"ROWJSON,{json.dumps(row)}", flush=True)
-
-
-if __name__ == "__main__":
-    if len(sys.argv) == 3 and sys.argv[1] == "--sharded-worker":
-        _sharded_worker(int(sys.argv[2]))
-    else:
-        print("usage: python -m benchmarks.run qps_recall", file=sys.stderr)
-        sys.exit(2)
+    for devices in SHARDED_DEVICE_COUNTS:
+        if devices > len(jax.devices()):
+            break
+        devs = jax.devices()[:devices]
+        cells = [
+            # All devices on `model`: every added device grows the servable
+            # graph -- the capability the model-axis sweep exists to measure.
+            (f"fig9_sharded_d{devices}", (1, devices), "sharded"),
+            (f"fig9_sharded_base_d{devices}", (1, devices), "sharded-base"),
+        ]
+        if devices > 1:
+            # All devices on `data`: the query-parallel scaling curve. At
+            # devices=1 this cell would duplicate fig9_sharded_d1 exactly.
+            cells.append(
+                (f"fig9_dataparallel_d{devices}", (devices, 1), "sharded")
+            )
+        for name, mesh_shape, variant in cells:
+            mesh = make_mesh(mesh_shape, ("data", "model"), devices=devs)
+            ex = ShardedSearchExecutor.from_index(idx, mesh, variant=variant)
+            pipe = ServePipeline(ex, k=k, cfg=cfg, max_batch=SHARDED_BATCH)
+            r, best_qps, best_wall, warm = _steady_state(pipe, queries, gt)
+            row = sharded_row(
+                name, ex, devices, r, best_qps,
+                best_wall / len(queries) * 1e6, warm.compile_s,
+            )
+            print(f"ROWJSON,{json.dumps(row)}", flush=True)
+            report(row["name"], row["us_per_query"], _row_derived(row))

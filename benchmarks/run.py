@@ -80,6 +80,10 @@ def main() -> None:
                          "replaced by the suite name")
     args = ap.parse_args()
 
+    from repro.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
+
     from . import (
         bench_ablations,
         bench_compression,

@@ -26,18 +26,17 @@ link; the server prints the measured hit rate and bytes saved), and
 issued while the device merges hop k; the server prints the measured overlap
 fraction). `--result-cache N` enables the ServePipeline cross-batch
 query-result LRU (any variant). `--autotune` sweeps the fused megakernel's
-scheduling knobs (eager/lazy §4.6 selection, beyond-VMEM DMA tile size) on
+scheduling knobs (eager/lazy §4.6 selection, VMEM-resident vs HBM codes) on
 real searches before serving and persists the winners to `--autotune-cache`
 (JSON keyed by device kind, bucket, R, m); a pre-existing cache file is
-applied even without the sweep, and the latency-hiding XLA scheduler flags
-are installed before the backend initialises. `--mutate` interleaves live
-inserts/deletes
+applied even without the sweep. `--mutate` interleaves live inserts/deletes
 with the serving batches through a `MutableBangIndex` (plus a background
 consolidation halfway through), scoring recall against the live corpus.
-On a CPU host `--devices N` forces N fake
-devices (set before any other use of jax in the process, which this
-entrypoint guarantees by setting XLA_FLAGS first). See `--help` for the
-variant x placement, kernel-mode and host-I/O matrices.
+For a CPU rehearsal, `--devices N` forces N fake host devices (set before
+any other use of jax in the process); on a TPU backend the sharded variants
+use the real devices and `--devices` is refused. Compiles are cached under
+$JAX_COMPILATION_CACHE_DIR, else `.jax_cache/` at the repo root. See
+`--help` for the variant x placement, kernel-mode and host-I/O matrices.
 
     PYTHONPATH=src python examples/serve_ann.py --batches 5 --batch-size 128
     PYTHONPATH=src python examples/serve_ann.py --variant sharded --devices 4
@@ -310,21 +309,26 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.devices > 0:
-        # Must land before jax initializes its backend; imports below are
-        # deferred past argparse for exactly this reason.
+        # Fake CPU devices for a rehearsal. Must land before jax initializes
+        # its backend; imports below are deferred past argparse for exactly
+        # this reason.
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.devices}"
         ).strip()
 
-    # Latency-hiding scheduler flags must also land before backend init
-    # (repro.kernels.autotune imports no jax at module level, so this is
-    # still pre-backend). Idempotent; explicit caller XLA_FLAGS win.
-    from repro.kernels.autotune import AutotuneCache, setup_xla_flags
-
-    setup_xla_flags()
-
     import jax
+
+    if args.devices > 0 and jax.default_backend() == "tpu":
+        raise SystemExit(
+            "--devices forces fake CPU devices; on a TPU backend the sharded "
+            "variants use the real devices: drop --devices"
+        )
+
+    from repro.compile_cache import setup_compile_cache
+    from repro.kernels.autotune import AutotuneCache
+
+    setup_compile_cache()
 
     from repro.core import BangIndex, SearchConfig, brute_force_knn
     from repro.data import gaussian_mixture, uniform_queries

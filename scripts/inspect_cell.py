@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # the 512 fake devices are CPU devices
 """Hillclimb profiler: compile the 1-unit unrolled program for a cell and
 print the largest collectives + a bytes-by-op-kind breakdown from the
 optimized HLO. This is the 'profile' of the dry-run methodology.
@@ -15,7 +16,6 @@ import jax
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.compat import named_shardings, set_mesh  # noqa: E402
 from repro.launch.dryrun import _COLL_RE, _shape_bytes, _unrolled_cfgs  # noqa: E402
 
 
@@ -39,9 +39,9 @@ def main() -> None:
     mesh = make_production_mesh(multi_pod=args.multi_pod)
     shape = LM_SHAPES[args.shape]
     step, specs, shardings = step_and_specs(cfg_u, shape, mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = (
-            jax.jit(step, in_shardings=named_shardings(mesh, shardings))
+            jax.jit(step, in_shardings=shardings)
             .lower(*specs).compile()
         )
     hlo = compiled.as_text()

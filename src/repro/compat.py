@@ -1,139 +1,54 @@
-"""JAX API compatibility layer (mesh / shard_map drift).
+"""Thin wrappers over the JAX mesh / callback APIs the repo uses everywhere.
 
-The repo targets the modern mesh API (`jax.shard_map`, `jax.set_mesh`,
-`jax.make_mesh(..., axis_types=...)`, `check_vma=`); older JAX releases (the
-0.4.x line this container ships) expose the same machinery under
-`jax.experimental.shard_map.shard_map`, `with mesh:`, plain `jax.make_mesh`
-and `check_rep=`. Every mesh-touching module imports these wrappers instead
-of probing `jax` itself, so the sharded search, the pjit dry-run tools and
-the multidevice tests run unmodified on both API generations.
+Every mesh-touching module imports these instead of spelling the arguments
+out itself, so one place fixes the choices: meshes with Auto axis types,
+`shard_map` with the replication checker on by default, and host callbacks
+called once per batch member (never claimed to vectorize).
 
 Keep this module dependency-free (jax only): it is imported by `core`,
 `launch`, `models`, tests and subprocess snippets alike.
 """
 from __future__ import annotations
 
-import contextlib
-import inspect
 from typing import Sequence
 
 import jax
 
-__all__ = [
-    "make_mesh", "set_mesh", "shard_map", "pure_callback", "named_shardings",
-    "abstract_mesh", "ambient_mesh",
-]
+__all__ = ["make_mesh", "shard_map", "pure_callback"]
 
 
-def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """`jax.make_mesh` with explicit Auto axis_types where supported."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)),
-        )
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-
-
-def set_mesh(mesh):
-    """Context manager making `mesh` ambient (`jax.set_mesh` / `with mesh:`).
-
-    New JAX: `jax.set_mesh(mesh)` is itself a context manager. Old JAX: the
-    concrete `Mesh` is the context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = True):
-    """`jax.shard_map` (new, `check_vma=`) or the experimental one (`check_rep=`).
-
-    `check_rep` defaults to True to match upstream (replication claims in
-    out_specs are validated at trace time); the sharded-search call sites
-    opt out explicitly because their psum-reconstructed outputs defeat the
-    checker.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_rep,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_rep)
-
-
-def pure_callback(fn, result_shape_dtypes, *args):
-    """`jax.pure_callback` across the vmap-API drift, shard_map-safe.
-
-    Newer JAX spells the batching rule `vmap_method=`; the early 0.4.x line
-    only knows `vectorized=` (and warns-then-errors on the new kwarg). Both
-    spellings below mean the same thing -- "call the host fn once per
-    batch member, never claim it vectorizes" -- which is also the only rule
-    that is safe under `shard_map`, where the callback runs once per device
-    with that device's local block. Host-service call sites (the BANG base
-    and sharded-base graph callbacks, the host re-rank gather) go through
-    here instead of probing `jax` themselves.
-    """
-    if "vmap_method" in inspect.signature(jax.pure_callback).parameters:
-        return jax.pure_callback(
-            fn, result_shape_dtypes, *args, vmap_method="sequential"
-        )
-    return jax.pure_callback(fn, result_shape_dtypes, *args, vectorized=False)
-
-
-def named_shardings(mesh, tree):
-    """Map a PartitionSpec tree to NamedShardings over `mesh`.
-
-    New JAX lets `jax.jit(in_shardings=...)` take bare PartitionSpecs under
-    an ambient `jax.set_mesh`; 0.4.x requires concrete `Sharding` objects.
-    NamedSharding works on both generations, so converting is the portable
-    form. Non-PartitionSpec leaves (already-concrete shardings) pass through.
-    """
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s) if isinstance(s, PartitionSpec) else s,
-        tree,
-        is_leaf=lambda s: isinstance(s, PartitionSpec),
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
+              devices=None):
+    """`jax.make_mesh` with explicit Auto axis types (over `devices` if given)."""
+    names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes), names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+        devices=devices,
     )
 
 
-def abstract_mesh(axes):
-    """`AbstractMesh` from ((name, size), ...) pairs on either generation.
+def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = True):
+    """`jax.shard_map`; `check_rep` is its `check_vma` replication checker.
 
-    0.4.x takes the pair tuple directly; newer JAX takes (sizes, names).
+    The sharded-search call sites opt out explicitly because their
+    psum-reconstructed outputs defeat the checker.
     """
-    from jax.sharding import AbstractMesh
-
-    pairs = tuple(axes)
-    try:
-        return AbstractMesh(pairs)
-    except TypeError:
-        return AbstractMesh(
-            tuple(s for _, s in pairs), tuple(n for n, _ in pairs)
-        )
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_rep,
+    )
 
 
-def ambient_mesh():
-    """The ambient mesh (abstract on new JAX, physical on 0.4.x), or None.
+def pure_callback(fn, result_shape_dtypes, *args):
+    """`jax.pure_callback` called once per batch member, shard_map-safe.
 
-    New JAX tracks the `jax.set_mesh` context through
-    `jax.sharding.get_abstract_mesh`; on 0.4.x the `with mesh:` context lands
-    in the thread-local physical mesh. Returns None when no mesh is set.
+    "sequential" never claims the host function vectorizes -- the only rule
+    that is safe under `shard_map`, where the callback runs once per device
+    with that device's local block. Host-service call sites (the BANG base
+    and sharded-base graph callbacks, the host re-rank gather) go through
+    here.
     """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        with contextlib.suppress(Exception):
-            mesh = getter()
-            return mesh if getattr(mesh, "axis_names", ()) else None
-    with contextlib.suppress(Exception):
-        from jax._src.mesh import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-        return mesh if mesh.axis_names else None
-    return None
-
-
+    return jax.pure_callback(
+        fn, result_shape_dtypes, *args, vmap_method="sequential"
+    )

@@ -38,13 +38,12 @@ Legacy `SearchConfig(use_kernels=True)` is an alias for
 Beyond-VMEM regime (fallback rules): "fused" NEVER silently falls back to
 "staged". When the PQ-codes block exceeds the VMEM budget
 (`REPRO_VMEM_BUDGET` env, 16 MiB default -- the billion-scale shard regime)
-the megakernel keeps the block in HBM and streams it through a
-double-buffered DMA pipeline: the async copy of code tile i+1 overlaps the
-ADC contraction on tile i, and every candidate lane's distance comes from
-its single owning tile, so results stay bit-exact vs the resident kernel
-and every other mode. The DMA tile size is `SearchConfig.codes_tile_rows`
-(0 = auto-sized from the budget); `repro.kernels.autotune` sweeps it with
-the eager/lazy §4.6 selection flavour per batch bucket and persists winners
+the megakernel keeps the packed code lines in HBM and fetches each
+candidate's 512-byte line by DMA, so a hop reads B x R lines, not the
+block; results stay bit-exact vs the resident kernel and every other mode.
+`SearchConfig.codes_tile_rows` > 0 forces that HBM placement (0 = decided
+from the budget); `repro.kernels.autotune` sweeps the placement with the
+eager/lazy §4.6 selection flavour per batch bucket and persists winners
 as JSON keyed by (device kind, bucket, R, m), which executors built with
 `autotune=` apply inside the compile-cache key. A missing/corrupt winners
 file degrades to default configs with a warning.
@@ -171,6 +170,7 @@ results are byte-identical attached or detached. Four components:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -225,8 +225,9 @@ class BangIndex:
         graph: VamanaGraph | None = None,
     ) -> "BangIndex":
         data = np.asarray(data, np.float32)
-        codec = pqlib.train_pq(jnp.asarray(data), m, iters=kmeans_iters)
-        codes = pqlib.pq_encode(codec, jnp.asarray(data))
+        data_dev = jnp.asarray(data)          # one upload serves all three uses
+        codec = pqlib.train_pq(data_dev, m, iters=kmeans_iters)
+        codes = pqlib.pq_encode(codec, data_dev)
         if graph is None:
             graph = build_vamana(data, R=R, L=L_build, alpha=alpha, seed=seed)
         return cls(
@@ -234,7 +235,7 @@ class BangIndex:
             codes=codes,
             graph=graph,
             data_np=data,
-            data_dev=jnp.asarray(data) if keep_device_data else None,
+            data_dev=data_dev if keep_device_data else None,
         )
 
     @property
@@ -359,15 +360,43 @@ class BangIndex:
 
 def brute_force_knn(data: np.ndarray, queries: np.ndarray, k: int) -> np.ndarray:
     """Ground truth for recall measurements (O(nd) per query)."""
-    data = jnp.asarray(data, jnp.float32)
-    queries = jnp.asarray(queries, jnp.float32)
-    d2 = (
-        jnp.sum(queries * queries, -1)[:, None]
-        + jnp.sum(data * data, -1)[None, :]
-        - 2.0 * queries @ data.T
-    )
-    _, idx = jax.lax.top_k(-d2, k)
-    return np.asarray(idx)
+    return np.asarray(_knn(jnp.asarray(data, jnp.float32),
+                           jnp.asarray(queries, jnp.float32), k))
+
+
+# Corpus rows scored per step: bounds the (B, rows) distance block, so the
+# ground truth of a corpus of any size fits next to the corpus itself.
+_KNN_ROWS = 65536
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _knn(data: Array, queries: Array, k: int) -> Array:
+    n = data.shape[0]
+    rows = min(n, _KNN_ROWS)
+    steps = -(-n // rows)
+    qn = jnp.sum(queries * queries, -1)[:, None]
+
+    def step(best, s):
+        # The last step starts early enough to end at row n; rows an
+        # earlier step scored are masked, so the corpus is never padded.
+        start = jnp.minimum(s * rows, n - rows)
+        x = jax.lax.dynamic_slice_in_dim(data, start, rows)
+        ids = start + jnp.arange(rows, dtype=jnp.int32)
+        d2 = qn + jnp.sum(x * x, -1)[None, :] - 2.0 * jnp.dot(
+            queries, x.T, precision=jax.lax.Precision.HIGHEST
+        )
+        d2 = jnp.where(ids >= s * rows, d2, jnp.inf)
+        cand_d = jnp.concatenate([best[0], d2], axis=1)
+        cand_i = jnp.concatenate(
+            [best[1], jnp.broadcast_to(ids, d2.shape)], axis=1
+        )
+        neg, pos = jax.lax.top_k(-cand_d, k)
+        return (-neg, jnp.take_along_axis(cand_i, pos, axis=1)), None
+
+    B = queries.shape[0]
+    init = (jnp.full((B, k), jnp.inf), jnp.full((B, k), -1, jnp.int32))
+    (_, idx), _ = jax.lax.scan(step, init, jnp.arange(steps))
+    return idx
 
 
 def recall_at_k(found_ids: np.ndarray, true_ids: np.ndarray) -> float:

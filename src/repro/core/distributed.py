@@ -156,25 +156,27 @@ def sharded_adc_distance_fn(
 
       "reference"  XLA gather + take_along_axis ADC
       "staged"     XLA gather into a (B, R, m) HBM temporary + pq_adc kernel
-      "fused"      search_step.local_adc -- the gather happens *inside* the
-                   kernel on the shard's codes block (VMEM-resident while it
-                   fits the budget, DMA-pipelined from HBM beyond it --
-                   `codes_tile_rows` follows resolve_codes_tiling), masked
-                   to the rows this shard owns; no HBM temporary.
+      "fused"      search_step.local_adc -- the fetch happens *inside* the
+                   kernel on the shard's packed codes (VMEM-resident while
+                   they fit the budget, fetched from HBM by row DMA beyond
+                   it -- `codes_tile_rows` follows codes_resident),
+                   masked to the rows this shard owns; no HBM temporary.
 
     All three contribute bit-identical owner rows (0 elsewhere), so the psum
     reconstruction -- and therefore the traversal -- is mode-independent.
     """
     n_loc = codes_local.shape[0]
     mode = kernel_mode or ("staged" if use_kernels else "reference")
+    if mode == "fused":
+        from repro.kernels.search_step import ops as step_ops
+
+        lines_local = step_ops.code_lines(codes_local)   # once, outside the loop
 
     def fn(ids: Array, valid: Array) -> Array:
         rel, own = _owned(n_loc, ids, axis)
         if mode == "fused":
-            from repro.kernels.search_step import ops as step_ops
-
             d = step_ops.local_adc(
-                table, codes_local, rel, own, tile_rows=codes_tile_rows
+                table, lines_local, n_loc, rel, own, tile_rows=codes_tile_rows
             )
         elif mode == "staged":
             from repro.kernels.pq_adc import ops as adc_ops
@@ -197,14 +199,8 @@ def sharded_exact_dists(
     """Owner-computed exact squared L2 + psum (re-rank stage, §4.9)."""
     n_loc = data_local.shape[0]
     rel, own = _owned(n_loc, ids, axis)
-    vecs = data_local[rel].astype(jnp.float32)            # (B, C, d)
-    q = queries.astype(jnp.float32)
-    d2 = (
-        jnp.sum(q * q, -1)[:, None]
-        + jnp.sum(vecs * vecs, -1)
-        - 2.0 * jnp.einsum("bcd,bd->bc", vecs, q)
-    )
-    d2 = jnp.where(own, d2, 0.0)
+    diff = data_local[rel].astype(jnp.float32) - queries.astype(jnp.float32)[:, None]
+    d2 = jnp.where(own, jnp.sum(diff * diff, -1), 0.0)    # same math as exact_topk
     d2 = jax.lax.psum(d2, axis)
     return jnp.where(ids == INVALID_ID, jnp.inf, d2)
 

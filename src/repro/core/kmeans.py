@@ -15,24 +15,36 @@ import jax.numpy as jnp
 Array = jax.Array
 
 
+def first_argmin(x: Array) -> Array:
+    """Index of the first minimum along the last axis, as `jnp.argmin` picks.
+
+    Spelled as a min, a compare and an iota-min: on a TPU v5e, `jnp.argmin`
+    over the last axis of the subspace-batched distance arrays below
+    returned wrong indices for most rows (CPU was right).
+    """
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    best = jnp.min(x, axis=-1, keepdims=True)
+    return jnp.min(jnp.where(x == best, iota, x.shape[-1]), axis=-1)
+
+
 def _pairwise_sq_dists(x: Array, c: Array) -> Array:
     """(n, d) x (k, d) -> (n, k) squared L2 distances via the matmul identity."""
     xn = jnp.sum(x * x, axis=-1, keepdims=True)           # (n, 1)
     cn = jnp.sum(c * c, axis=-1)[None, :]                 # (1, k)
-    return xn + cn - 2.0 * (x @ c.T)
+    return xn + cn - 2.0 * jnp.dot(x, c.T, precision=jax.lax.Precision.HIGHEST)
 
 
 def _lloyd_iter(x: Array, centroids: Array) -> tuple[Array, Array]:
     """One Lloyd iteration. Returns (new_centroids, assignment)."""
     d2 = _pairwise_sq_dists(x, centroids)                 # (n, k)
-    assign = jnp.argmin(d2, axis=-1)                      # (n,)
+    assign = first_argmin(d2)                             # (n,)
     k = centroids.shape[0]
     onehot = jax.nn.one_hot(assign, k, dtype=x.dtype)     # (n, k)
     counts = jnp.sum(onehot, axis=0)                      # (k,)
-    sums = onehot.T @ x                                   # (k, d)
+    sums = jnp.dot(onehot.T, x, precision=jax.lax.Precision.HIGHEST)  # (k, d)
     new_c = sums / jnp.maximum(counts, 1.0)[:, None]
     # Empty-cluster repair: pull the point farthest from its centroid.
-    far_idx = jnp.argmax(jnp.min(d2, axis=-1))
+    far_idx = first_argmin(-jnp.min(d2, axis=-1))
     new_c = jnp.where((counts == 0)[:, None], x[far_idx][None, :], new_c)
     return new_c, assign
 
@@ -56,7 +68,7 @@ def kmeans(x: Array, k: int, iters: int = 12, *, key: Array | None = None) -> tu
         return c, None
 
     centroids, _ = jax.lax.scan(body, init, None, length=iters)
-    assign = jnp.argmin(_pairwise_sq_dists(x, centroids), axis=-1)
+    assign = first_argmin(_pairwise_sq_dists(x, centroids))
     return centroids, assign
 
 

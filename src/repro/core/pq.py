@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kmeans import kmeans_per_subspace
+from .kmeans import first_argmin, kmeans_per_subspace
 
 Array = jax.Array
 
@@ -79,22 +79,45 @@ def train_pq(data: Array, m: int, *, iters: int = 12, sample: int | None = 65536
     return PQCodec(codebooks)
 
 
+# Rows encoded per step: the (rows, m, 256) f32 distance block of one step
+# stays near 512 MiB at m = 32, whatever the corpus size.
+_ENCODE_ROWS = 16384
+
+
 @jax.jit
 def pq_encode(codec: PQCodec, data: Array) -> Array:
-    """(n, d) -> (n, m) uint8 cluster ids (argmin centroid per subspace)."""
-    x_sub = split_subspaces(jnp.asarray(data, jnp.float32), codec.m)  # (m, n, dsub)
+    """(n, d) -> (n, m) uint8 cluster ids (argmin centroid per subspace).
 
-    def per_subspace(xs, cb):
-        # (n, dsub), (256, dsub) -> (n,)
-        d2 = (
-            jnp.sum(xs * xs, -1, keepdims=True)
-            + jnp.sum(cb * cb, -1)[None, :]
-            - 2.0 * xs @ cb.T
-        )
-        return jnp.argmin(d2, axis=-1)
+    Encodes `_ENCODE_ROWS` rows per step, so a corpus of any size never
+    materialises its full (n, m, 256) distance array. The last step starts
+    early enough to end at row n (it re-encodes a few rows identically),
+    so the corpus is never padded or copied. The nearest centroid is
+    `first_argmin` of one (rows, m, 256) distance block: `jnp.argmin` over
+    a subspace-vmapped block returned wrong codes on a TPU v5e.
+    """
+    x = jnp.asarray(data, jnp.float32)
+    n, d = x.shape
+    m, dsub = codec.m, codec.dsub
+    rows = min(n, _ENCODE_ROWS)
+    cb = codec.codebooks                                          # (m, 256, dsub)
+    cn = jnp.sum(cb * cb, -1)[None]                               # (1, m, 256)
 
-    codes = jax.vmap(per_subspace)(x_sub, codec.codebooks)  # (m, n)
-    return codes.T.astype(jnp.uint8)
+    def encode(block):                                            # -> (rows, m)
+        xb = jnp.pad(block, ((0, 0), (0, m * dsub - d))).reshape(rows, m, dsub)
+        d2 = jnp.sum(xb * xb, -1)[..., None] + cn - 2.0 * jnp.einsum(
+            "rjs,jcs->rjc", xb, cb, precision=jax.lax.Precision.HIGHEST
+        )                                                         # (rows, m, 256)
+        return first_argmin(d2)
+
+    def step(s, codes):
+        start = jnp.minimum(s * rows, n - rows)
+        block = jax.lax.dynamic_slice_in_dim(x, start, rows)
+        return jax.lax.dynamic_update_slice_in_dim(codes, encode(block), start, 0)
+
+    codes = jax.lax.fori_loop(
+        0, -(-n // rows), step, jnp.zeros((n, m), jnp.int32)
+    )
+    return codes.astype(jnp.uint8)
 
 
 @jax.jit
@@ -118,7 +141,7 @@ def build_dist_table(codec: PQCodec, queries: Array) -> Array:
         return (
             jnp.sum(qs * qs, -1, keepdims=True)
             + jnp.sum(cb * cb, -1)[None, :]
-            - 2.0 * qs @ cb.T
+            - 2.0 * jnp.dot(qs, cb.T, precision=jax.lax.Precision.HIGHEST)
         )  # (B, 256)
 
     table = jax.vmap(per_subspace)(q_sub, codec.codebooks)  # (m, B, 256)
@@ -140,7 +163,12 @@ def adc_distance(table: Array, codes: Array) -> Array:
         idx[:, :, :, None],                                         # (B, R, m, 1)
         axis=3,
     )[..., 0]
-    return jnp.sum(gathered, axis=-1)
+    # Added in subspace order, as the Pallas ADC kernels add them, so every
+    # kernel mode scores a candidate bit-identically.
+    acc = gathered[..., 0]
+    for j in range(1, gathered.shape[-1]):
+        acc = acc + gathered[..., j]
+    return acc
 
 
 def quantization_error(codec: PQCodec, data: Array) -> float:

@@ -83,13 +83,8 @@ def exact_topk(
 
         d2 = rr_ops.exact_sq_dists(queries, cand_vecs)
     else:
-        q = queries.astype(jnp.float32)
-        v = cand_vecs.astype(jnp.float32)
-        d2 = (
-            jnp.sum(q * q, -1)[:, None]
-            + jnp.sum(v * v, -1)
-            - 2.0 * jnp.einsum("bcd,bd->bc", v, q)
-        )
+        diff = cand_vecs.astype(jnp.float32) - queries.astype(jnp.float32)[:, None]
+        d2 = jnp.sum(diff * diff, -1)
     d2 = jnp.where(cand_ids == INVALID_ID, jnp.inf, d2)
     # Dedup: the same node can appear at most once in history by construction
     # (bloom filter), so no mask needed beyond padding.

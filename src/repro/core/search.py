@@ -81,11 +81,12 @@ class SearchConfig:
     eager: bool = True           # §4.6 eager candidate selection
     use_kernels: bool = False    # legacy alias for kernel_mode="staged"
     kernel_mode: str | None = None  # "reference" | "staged" | "fused"
-    # Fused-kernel codes placement (kernels.search_step.resolve_codes_tiling):
-    # 0 auto-places the PQ codes block (VMEM-resident while it fits the
-    # budget, DMA-pipelined from HBM beyond it); > 0 forces that DMA tile
-    # row count -- the autotuner's knob. All placements are bit-identical;
-    # non-fused modes ignore it (but it still keys compiled executables).
+    # Fused-kernel codes placement (kernels.search_step.ops.codes_resident):
+    # 0 auto-places the packed PQ codes (VMEM-resident while they fit the
+    # budget, else in HBM with one row DMA per candidate); > 0 forces the
+    # HBM placement -- the autotuner's knob. All placements are
+    # bit-identical; non-fused modes ignore it (but it still keys compiled
+    # executables).
     codes_tile_rows: int = 0
 
     def __post_init__(self) -> None:
@@ -333,11 +334,11 @@ class FusedTraverseStep(StepFn):
 class FusedStep(StepFn):
     """The whole iteration body in one search_step megakernel.
 
-    The code gather happens *inside* the kernel (satisfying the VMEM-only
+    The code fetch happens *inside* the kernel (satisfying the VMEM-only
     candidate path): no (B, R, m) gathered-codes HBM temporary, no (B, R)
-    intermediate tiles between stages. `tile_rows` picks the codes-block
-    placement (0 = auto: VMEM-resident while it fits the budget, else the
-    double-buffered DMA pipeline) -- beyond-VMEM blocks stream from HBM
+    intermediate tiles between stages. `tile_rows` picks the codes
+    placement (0 = auto: VMEM-resident while it fits the budget, else in HBM
+    with one row DMA per candidate) -- beyond-VMEM blocks are read from HBM
     instead of falling back to the staged path, bit-identically.
     """
 
@@ -345,8 +346,12 @@ class FusedStep(StepFn):
         self, table: Array, codes: Array, eager: bool = True,
         tile_rows: int = 0,
     ) -> None:
+        from repro.kernels.search_step import ops as step_ops
+
         self.table = table
         self.codes = codes
+        # Packed once per search, before the loop: the kernel's row format.
+        self.lines = step_ops.code_lines(codes)
         self.eager = eager
         self.tile_rows = tile_rows
 
@@ -366,8 +371,8 @@ class FusedStep(StepFn):
         from repro.kernels.search_step import ops as step_ops
 
         return step_ops.fused_step(
-            self.table, self.codes, wl, nbrs, fresh, active,
-            eager=self.eager, tile_rows=self.tile_rows,
+            self.table, self.lines, self.codes.shape[0], wl, nbrs, fresh,
+            active, eager=self.eager, tile_rows=self.tile_rows,
         )
 
 
@@ -420,7 +425,10 @@ def _exact_distance_fn(data: Array, queries: Array) -> DistanceFn:
         safe = jnp.where(valid, ids, 0)
         vecs = data[safe].astype(jnp.float32)         # (B, R, d)
         vn = jnp.sum(vecs * vecs, axis=-1)            # (B, R)
-        dot = jnp.einsum("brd,bd->br", vecs, queries.astype(jnp.float32))
+        dot = jnp.einsum(
+            "brd,bd->br", vecs, queries.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
         d = qn[:, None] + vn - 2.0 * dot
         return jnp.where(valid, d, jnp.inf)
 

@@ -1,9 +1,9 @@
 """Autotuner for the fused traversal megakernel (beyond-VMEM DMA regime).
 
-The fused search-step kernel now has real scheduling knobs: the codes-block
-placement (`SearchConfig.codes_tile_rows` -- VMEM-resident vs the
-double-buffered DMA pipeline, and the DMA tile size) and the §4.6 selection
-flavour (`eager`). The right settings depend on the device, the batch
+The fused search-step kernel has two scheduling knobs: the codes placement
+(`SearchConfig.codes_tile_rows` -- VMEM-resident lines, or lines left in HBM
+and fetched by row DMA) and the §4.6 selection flavour (`eager`). The right
+settings depend on the device, the batch
 bucket, the adjacency fan-out R and the PQ subspace count m -- exactly the
 per-device tile tuning CAGRA-class GPU implementations rely on. This module
 makes that tuning a persisted artifact instead of a per-process guess:
@@ -20,10 +20,6 @@ makes that tuning a persisted artifact instead of a per-process guess:
     (`SearchExecutor._compiled`), so the tuned fields ride the key: a
     reloaded cache file reproduces the exact same executor compile-cache
     keys, and differently-tuned configs never share executables.
-  * `setup_xla_flags()` applies the latency-hiding XLA scheduler flags that
-    let the compiled pipeline overlap the DMA/collective traffic the tuned
-    kernel schedules; call it before the first JAX computation (flags are
-    read at backend initialisation).
 
 Schema (version 1)::
 
@@ -48,8 +44,6 @@ __all__ = [
     "autotune_executor",
     "device_kind",
     "default_tile_candidates",
-    "setup_xla_flags",
-    "LATENCY_HIDING_XLA_FLAGS",
 ]
 
 SCHEMA_VERSION = 1
@@ -61,34 +55,6 @@ _WINNER_FIELDS = (
     ("codes_tile_rows", int),
     ("per_hop_us", (int, float)),
 )
-
-# Latency-hiding scheduling: overlap the tuned kernel's DMA/collective
-# traffic with compute at the XLA level too. GPU-prefixed flags are inert on
-# other backends (but must still be *known* to the build: XLA aborts on
-# unknown flags, so only flags the pinned toolchain registers belong here);
-# they are appended (never overwriting caller flags) so an explicit
-# XLA_FLAGS env always wins.
-LATENCY_HIDING_XLA_FLAGS = (
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-    "--xla_gpu_enable_highest_priority_async_stream=true",
-)
-
-
-def setup_xla_flags(flags: tuple[str, ...] = LATENCY_HIDING_XLA_FLAGS) -> str:
-    """Append missing latency-hiding flags to XLA_FLAGS (idempotent).
-
-    Must run before JAX initialises its backend to take effect; returns the
-    resulting XLA_FLAGS value. Flags already set by the caller (same
-    `--flag=` prefix, any value) are left untouched.
-    """
-    current = os.environ.get("XLA_FLAGS", "")
-    have = {f.split("=", 1)[0] for f in current.split() if f}
-    add = [f for f in flags if f.split("=", 1)[0] not in have]
-    if add:
-        current = " ".join([*current.split(), *add])
-        os.environ["XLA_FLAGS"] = current
-    return current
-
 
 def device_kind() -> str:
     """The accelerator kind string the winners are keyed by (e.g. "cpu",
@@ -216,21 +182,15 @@ class AutotuneCache:
 def default_tile_candidates(n: int, m: int) -> tuple[int, ...]:
     """Candidate `codes_tile_rows` values for an (n, m) codes block.
 
-    0 (auto placement) is always swept. When the block exceeds the VMEM
-    budget, the auto tile size and its pow2 neighbours join the sweep --
-    the tile/grid shape axis of the search space; resident blocks have no
-    tile axis to sweep.
+    0 (auto placement) is always swept; a block that fits the VMEM budget
+    also sweeps a forced HBM placement. The HBM path fetches rows, not
+    tiles, so one forced value covers it.
     """
-    from repro.kernels.search_step.ops import resolve_codes_tiling
+    from repro.kernels.search_step.ops import codes_resident
 
-    auto = resolve_codes_tiling(n, m, 0)
-    if auto == 0:
+    if not codes_resident(n, m) or n <= 8:
         return (0,)
-    cands = {0, auto}
-    for tile in (auto // 2, auto * 2):
-        if 8 <= tile < n:
-            cands.add(tile)
-    return tuple(sorted(cands))
+    return (0, 8)
 
 
 def autotune_executor(

@@ -3,18 +3,21 @@
 The paper sorts <=64-entry neighbour lists with a parallel bottom-up merge
 sort and merges them into the worklist with the merge-path algorithm (one
 thread per element + binary search), both in GPU shared memory. TPUs have no
-per-lane scatter/binary-search, so we ADAPT (DESIGN.md §2): a bitonic
-compare-exchange network whose every stage is a reshape + elementwise min/max
-over VMEM-resident tiles -- the canonical lane-friendly sorting network.
+per-lane scatter/binary-search, so we ADAPT: a bitonic compare-exchange
+network over (8, W) VMEM tiles -- 8 queries on the sublanes, W >= 128 lanes.
+Every stage fetches each lane's XOR partner with two lane rotations and a
+select, so the network needs no reshape, reversal or gather (none of which
+Mosaic lowers on lane-sized tiles).
 
-  * sort:  full bitonic network, O(log^2 n) stages of (B, n) tiles.
-  * merge: the two inputs are already sorted; concatenating list 1 with the
-    *reverse* of list 2 yields a bitonic sequence, so only the final merge
-    phase (log n stages) runs -- the exact work-complexity analogue of the
-    paper's merge-path step (O(l log l) work, O(log l) span).
+  * sort:  full bitonic network over aligned blocks of n lanes. The last
+    stage sorts even blocks ascending and odd blocks descending, which the
+    fused search step uses to get a reversed candidate list for free.
+  * merge: list 1 ascending ++ list 2 descending is a bitonic sequence, so
+    only the final merge phase (log W stages) runs -- the work-complexity
+    analogue of the paper's merge-path step (O(l log l) work, O(log l) span).
 
-Keys are (dist, id) lexicographic; payloads (id, visited) ride along through
-the same where-masks. Padding uses (+inf, INT32_MAX, visited=1), which sorts
+Keys are (dist, id) lexicographic; payloads (visited) ride along through
+the same selects. Padding uses (+inf, INT32_MAX, visited=1), which sorts
 last and never blocks convergence.
 """
 from __future__ import annotations
@@ -25,6 +28,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.common import LANES, next_pow2
 
 # numpy (not jnp) scalar: this module is imported lazily from *inside*
 # traced step functions, and a module-level jnp constant created while a
@@ -33,52 +39,63 @@ from jax.experimental import pallas as pl
 # identically in jnp expressions.
 INT_MAX = np.int32(2**31 - 1)
 
+BROWS = 8  # queries per program: one sublane tile
 
-def _compare_exchange(d, i, v, j: int, k: int):
-    """One bitonic stage: partner = idx ^ j, direction from bit k of idx.
 
-    Implemented with reshapes (n // 2j, 2, j): the XOR-partner of every
-    element in the leading half of a 2j block is the matching element of the
-    trailing half; direction (ascending iff (idx & k) == 0) is constant per
-    2j-block and computed from a block iota.
+def lane_iota(shape) -> jax.Array:
+    return jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+
+
+def xor_partner(x: jax.Array, j: int, lane: jax.Array) -> jax.Array:
+    """x[..., lane ^ j] for a power of two j < width, by lane rotation.
+
+    Both rotations are taken and the one whose rotated lane index equals the
+    partner is kept, so the result does not depend on the rotation's sign
+    convention.
     """
-    B, n = d.shape
-    g = n // (2 * j)
-    d3 = d.reshape(B, g, 2, j)
-    i3 = i.reshape(B, g, 2, j)
-    v3 = v.reshape(B, g, 2, j)
-    a_d, b_d = d3[:, :, 0, :], d3[:, :, 1, :]
-    a_i, b_i = i3[:, :, 0, :], i3[:, :, 1, :]
-    a_v, b_v = v3[:, :, 0, :], v3[:, :, 1, :]
+    n = x.shape[-1]
+    axis = x.ndim - 1
+    fwd = pltpu.roll(lane, j, axis) == (lane ^ j)
+    return jnp.where(fwd, pltpu.roll(x, j, axis), pltpu.roll(x, n - j, axis))
 
-    # ascending iff bit k of the absolute index is 0; abs idx of block g row
-    # starts at g*2j, and within a 2j block bit k is constant since k >= 2j.
-    blk = jax.lax.broadcasted_iota(jnp.int32, (1, g, 1), 1)
-    asc = ((blk * (2 * j)) & k) == 0                              # (1, g, 1)
 
-    a_gt_b = (a_d > b_d) | ((a_d == b_d) & (a_i > b_i))
-    swap = jnp.where(asc, a_gt_b, ~a_gt_b)                        # (B, g, j)
+def _compare_exchange(d, i, v, j: int, k: int, lane):
+    """One bitonic stage: partner = lane ^ j, ascending iff (lane & k) == 0.
 
-    new_a_d = jnp.where(swap, b_d, a_d)
-    new_b_d = jnp.where(swap, a_d, b_d)
-    new_a_i = jnp.where(swap, b_i, a_i)
-    new_b_i = jnp.where(swap, a_i, b_i)
-    new_a_v = jnp.where(swap, b_v, a_v)
-    new_b_v = jnp.where(swap, a_v, b_v)
-
-    d = jnp.stack([new_a_d, new_b_d], axis=2).reshape(B, n)
-    i = jnp.stack([new_a_i, new_b_i], axis=2).reshape(B, n)
-    v = jnp.stack([new_a_v, new_b_v], axis=2).reshape(B, n)
+    The pair (a, b) = (lower, upper) lane swaps iff a > b on an ascending
+    block (a < b on a descending one); both lanes of a pair compute the same
+    decision, so payloads of equal keys move exactly as in the classic
+    in-place network.
+    """
+    pd, pi = xor_partner(d, j, lane), xor_partner(i, j, lane)
+    upper = (lane & j) != 0
+    self_gt = (d > pd) | ((d == pd) & (i > pi))
+    partner_gt = (pd > d) | ((pd == d) & (pi > i))
+    # Boolean algebra, not selects: Mosaic has no select over i1 vectors.
+    a_gt_b = (upper & partner_gt) | (~upper & self_gt)
+    asc = (lane & k) == 0
+    swap = (a_gt_b & asc) | (~a_gt_b & ~asc)
+    d = jnp.where(swap, pd, d)
+    i = jnp.where(swap, pi, i)
+    if v is not None:
+        v = jnp.where(swap, xor_partner(v, j, lane), v)
     return d, i, v
 
 
 def bitonic_stages(d, i, v, n: int, full_sort: bool):
-    """full_sort: complete network; else only the final merge phase (k=n).
+    """Bitonic network on (Q, W) values, W a power of two >= n.
 
-    Pure function of (B, n) jnp values -- usable from any Pallas kernel body,
+    full_sort: the complete network over aligned n-lane blocks (even blocks
+    end ascending, odd blocks descending; n == W sorts the whole row
+    ascending). Otherwise only the final merge phase over all W lanes, which
+    sorts a bitonic row ascending. `v` may be None (no payload).
+
+    Pure function of jnp values -- usable from any Pallas kernel body,
     including the fused search_step megakernel (repro.kernels.search_step),
     which reuses it so the fused and staged sort/merge stay bit-identical.
     """
+    W = d.shape[-1]
+    lane = lane_iota(d.shape)
     ks = []
     if full_sort:
         k = 2
@@ -86,119 +103,100 @@ def bitonic_stages(d, i, v, n: int, full_sort: bool):
             ks.append(k)
             k *= 2
     else:
-        ks = [n]
+        ks = [W]
     for k in ks:
         j = k // 2
         while j >= 1:
-            d, i, v = _compare_exchange(d, i, v, j, k)
+            d, i, v = _compare_exchange(d, i, v, j, k, lane)
             j //= 2
     return d, i, v
 
 
-def _sort_kernel(d_ref, i_ref, out_d_ref, out_i_ref, *, n: int):
-    d, i = d_ref[...], i_ref[...]
-    v = jnp.zeros_like(i)
-    d, i, _ = bitonic_stages(d, i, v, n, full_sort=True)
+def reverse_blocks(x, n: int):
+    """Reverse every aligned n-lane block of x (n a power of two)."""
+    lane = lane_iota(x.shape)
+    j = 1
+    while j < n:
+        x = xor_partner(x, j, lane)
+        j *= 2
+    return x
+
+
+def _sort_kernel(d_ref, i_ref, out_d_ref, out_i_ref):
+    d, i, _ = bitonic_stages(
+        d_ref[...], i_ref[...], None, d_ref.shape[-1], full_sort=True
+    )
     out_d_ref[...] = d
     out_i_ref[...] = i
 
 
 def _merge_kernel(
     d1_ref, i1_ref, v1_ref, d2_ref, i2_ref, out_d_ref, out_i_ref, out_v_ref,
-    *, n: int, t: int
+    *, off: int, n2: int,
 ):
-    # list 1 ascending ++ reversed list 2 => bitonic sequence; merge phase only.
-    d = jnp.concatenate([d1_ref[...], d2_ref[...][:, ::-1]], axis=-1)
-    i = jnp.concatenate([i1_ref[...], i2_ref[...][:, ::-1]], axis=-1)
-    v2 = jnp.zeros_like(i2_ref[...])
-    v = jnp.concatenate([v1_ref[...], v2[:, ::-1]], axis=-1)
-    d, i, v = bitonic_stages(d, i, v, n, full_sort=False)
-    out_d_ref[...] = d[:, :t]
-    out_i_ref[...] = i[:, :t]
-    out_v_ref[...] = v[:, :t]
+    # list 1 sits in lanes [0, off), list 2 (ascending) in the n2-lane block
+    # at `off`; reversing that block makes the row bitonic.
+    lane = lane_iota(d1_ref.shape)
+    first = lane < off
+    d = jnp.where(first, d1_ref[...], reverse_blocks(d2_ref[...], n2))
+    i = jnp.where(first, i1_ref[...], reverse_blocks(i2_ref[...], n2))
+    v = jnp.where(first, v1_ref[...], 0)
+    d, i, v = bitonic_stages(d, i, v, d.shape[-1], full_sort=False)
+    out_d_ref[...] = d
+    out_i_ref[...] = i
+    out_v_ref[...] = v
 
 
-def _pad_pow2(d, i, v=None):
-    B, n = d.shape
-    p = 1
-    while p < n:
-        p *= 2
-    if p != n:
-        d = jnp.pad(d, ((0, 0), (0, p - n)), constant_values=jnp.inf)
-        i = jnp.pad(i, ((0, 0), (0, p - n)), constant_values=2**31 - 1)
-        if v is not None:
-            v = jnp.pad(v, ((0, 0), (0, p - n)), constant_values=1)
-    return (d, i, v, p) if v is not None else (d, i, p)
+def _pad2(x, rows: int, lanes_lo: int, lanes_hi: int, value):
+    return jnp.pad(x, ((0, rows), (lanes_lo, lanes_hi)), constant_values=value)
 
 
-BROWS = 8  # queries per program
+def _rows_call(kernel, width, rows, n_in, out_dtypes, *, interpret, name):
+    """pallas_call over (rows, width) arrays, BROWS rows per program."""
+    spec = pl.BlockSpec((BROWS, width), lambda b: (b, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(rows // BROWS,),
+        in_specs=[spec] * n_in,
+        out_specs=[spec] * len(out_dtypes),
+        out_shape=[jax.ShapeDtypeStruct((rows, width), dt) for dt in out_dtypes],
+        interpret=interpret,
+        name=name,
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def sort_kv_pallas(dists, ids, *, interpret: bool = True):
     """(B, n) sort ascending by (dist, id) via the bitonic network kernel."""
-    B, n0 = dists.shape
-    d, i, n = _pad_pow2(dists.astype(jnp.float32), ids.astype(jnp.int32))
+    B, n = dists.shape
+    W = max(LANES, next_pow2(n))
     pad_b = (-B) % BROWS
-    if pad_b:
-        d = jnp.pad(d, ((0, pad_b), (0, 0)), constant_values=jnp.inf)
-        i = jnp.pad(i, ((0, pad_b), (0, 0)), constant_values=2**31 - 1)
-    grid = ((B + pad_b) // BROWS,)
-    out_d, out_i = pl.pallas_call(
-        functools.partial(_sort_kernel, n=n),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((BROWS, n), lambda b: (b, 0)),
-            pl.BlockSpec((BROWS, n), lambda b: (b, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((BROWS, n), lambda b: (b, 0)),
-            pl.BlockSpec((BROWS, n), lambda b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B + pad_b, n), jnp.float32),
-            jax.ShapeDtypeStruct((B + pad_b, n), jnp.int32),
-        ],
-        interpret=interpret,
+    d = _pad2(dists.astype(jnp.float32), pad_b, 0, W - n, jnp.inf)
+    i = _pad2(ids.astype(jnp.int32), pad_b, 0, W - n, INT_MAX)
+    out_d, out_i = _rows_call(
+        _sort_kernel, W, B + pad_b, 2, (jnp.float32, jnp.int32),
+        interpret=interpret, name="bitonic_sort",
     )(d, i)
-    return out_d[:B, :n0], out_i[:B, :n0]
+    return out_d[:B, :n], out_i[:B, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("t", "interpret"))
 def merge_pallas(d1, i1, v1, d2, i2, *, t: int, interpret: bool = True):
-    """Merge sorted (d1,i1,v1) (len t) with sorted (d2,i2) (len R); keep t."""
-    B = d1.shape[0]
-    # pad the *combined* length to a power of two by padding list 2
-    n_tot = d1.shape[1] + d2.shape[1]
-    p = 1
-    while p < n_tot:
-        p *= 2
-    extra = p - n_tot
-    if extra:
-        d2 = jnp.pad(d2, ((0, 0), (0, extra)), constant_values=jnp.inf)
-        i2 = jnp.pad(i2, ((0, 0), (0, extra)), constant_values=2**31 - 1)
+    """Merge sorted (d1,i1,v1) (len t1) with sorted (d2,i2) (len R); keep t."""
+    B, t1 = d1.shape
+    R = d2.shape[1]
+    n2 = next_pow2(R)
+    W = max(LANES, next_pow2(t1 + n2))
+    off = W - n2                      # list 2's block: the last n2 lanes
     pad_b = (-B) % BROWS
-    if pad_b:
-        pads = lambda x, cv: jnp.pad(x, ((0, pad_b), (0, 0)), constant_values=cv)
-        d1, i1, v1 = pads(d1, jnp.inf), pads(i1, 2**31 - 1), pads(v1.astype(jnp.int32), 1)
-        d2, i2 = pads(d2, jnp.inf), pads(i2, 2**31 - 1)
-    else:
-        v1 = v1.astype(jnp.int32)
-    n1, n2 = d1.shape[1], d2.shape[1]
-    grid = ((B + pad_b) // BROWS,)
-    spec1 = pl.BlockSpec((BROWS, n1), lambda b: (b, 0))
-    spec2 = pl.BlockSpec((BROWS, n2), lambda b: (b, 0))
-    spec_o = pl.BlockSpec((BROWS, t), lambda b: (b, 0))
-    out_d, out_i, out_v = pl.pallas_call(
-        functools.partial(_merge_kernel, n=p, t=t),
-        grid=grid,
-        in_specs=[spec1, spec1, spec1, spec2, spec2],
-        out_specs=[spec_o, spec_o, spec_o],
-        out_shape=[
-            jax.ShapeDtypeStruct((B + pad_b, t), jnp.float32),
-            jax.ShapeDtypeStruct((B + pad_b, t), jnp.int32),
-            jax.ShapeDtypeStruct((B + pad_b, t), jnp.int32),
-        ],
-        interpret=interpret,
-    )(d1.astype(jnp.float32), i1.astype(jnp.int32), v1, d2.astype(jnp.float32), i2.astype(jnp.int32))
-    return out_d[:B], out_i[:B], out_v[:B].astype(jnp.bool_)
+    d1 = _pad2(d1.astype(jnp.float32), pad_b, 0, W - t1, jnp.inf)
+    i1 = _pad2(i1.astype(jnp.int32), pad_b, 0, W - t1, INT_MAX)
+    v1 = _pad2(v1.astype(jnp.int32), pad_b, 0, W - t1, 1)
+    d2 = _pad2(d2.astype(jnp.float32), pad_b, off, n2 - R, jnp.inf)
+    i2 = _pad2(i2.astype(jnp.int32), pad_b, off, n2 - R, INT_MAX)
+    out_d, out_i, out_v = _rows_call(
+        functools.partial(_merge_kernel, off=off, n2=n2), W, B + pad_b, 5,
+        (jnp.float32, jnp.int32, jnp.int32),
+        interpret=interpret, name="bitonic_merge",
+    )(d1, i1, v1, d2, i2)
+    return out_d[:B, :t], out_i[:B, :t], out_v[:B, :t].astype(jnp.bool_)

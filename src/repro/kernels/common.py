@@ -1,22 +1,20 @@
 """Shared Pallas kernel plumbing.
 
-TPU is the target (pl.pallas_call + BlockSpec VMEM tiling); on CPU the same
-kernels execute under interpret=True, which is how every kernel here is
-validated against its ref.py oracle. `INTERPRET` may be forced via the
-REPRO_PALLAS_INTERPRET env var (tests set it).
+TPU is the target (pl.pallas_call + BlockSpec VMEM tiling). On any other
+backend the same kernels execute under interpret=True, which is how every
+kernel here is validated against its ref.py oracle on CPU. On a TPU backend
+the kernels are always compiled: nothing can route a chip run through the
+interpreter.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
 
+LANES = 128  # lane width of a TPU vreg: the minor block dim of every kernel
+
 
 def interpret_mode() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() != "tpu"
 
 

@@ -10,7 +10,7 @@ from .pq_adc import adc_pallas
 from .ref import adc_ref
 
 
-def adc(table: jax.Array, codes: jax.Array, valid: jax.Array, *, variant: str = "onehot") -> jax.Array:
+def adc(table: jax.Array, codes: jax.Array, valid: jax.Array) -> jax.Array:
     """PQ asymmetric distances. table (B,m,256), codes (B,R,m), valid (B,R).
 
     Dispatches to the Pallas kernel (compiled on TPU, interpret elsewhere).
@@ -19,7 +19,6 @@ def adc(table: jax.Array, codes: jax.Array, valid: jax.Array, *, variant: str = 
         table.astype(jnp.float32),
         codes.astype(jnp.int32),
         valid,
-        variant=variant,
         interpret=interpret_mode(),
     )
 

@@ -3,22 +3,16 @@
 The paper's hottest kernel (~38% of billion-scale runtime): for each query and
 each of its R candidate neighbours, sum m per-subspace centroid distances out
 of the query's PQDistTable. The CUDA version tunes segmented warp reductions
-(atomics vs CUB WarpReduce); neither exists on TPU, so we ADAPT (DESIGN.md §2):
+(atomics vs CUB WarpReduce); neither exists on TPU, so we ADAPT: candidates
+sit on the sublanes, and each subspace's lookup is a one-hot select of the
+(1, 256) table row followed by a lane sum. The sum has exactly one non-zero
+term, so every lookup is exact, and the m lookups are added in subspace
+order -- the order `repro.core.pq.adc_distance` uses -- so the reference,
+staged and fused search paths produce bit-identical distances.
 
-  * one-hot × table contraction on the MXU ("onehot" variant, default):
-    codes (R, m) expand to one-hot (R, mc·256) per m-chunk and contract with
-    the table chunk -- a dense matmul the MXU executes at full rate; the
-    gather becomes structured compute instead of irregular memory traffic
-    (TPUs have no efficient per-lane gather, the exact inverse of the GPU
-    trade-off the paper tunes around).
-  * per-subspace dynamic-slice gather on the VPU ("gather" variant) for
-    comparison in benchmarks/bench_kernels.py, mirroring the paper's
-    atomicAdd-vs-WarpReduce ablation.
-
-Grid: one program per query (the paper's "one thread block per query"),
-R lanes wide. Table block (m, 256) f32 stays VMEM-resident across the m-chunk
-loop; m is padded to a multiple of MC with zero table entries (distance-
-neutral: padded subspaces contribute table[j, code]=0).
+Grid: 8 queries per program (one sublane tile). The (R, 1) column of each
+query's distances is turned into a lane row by a diagonal select, the lane
+layout the sort and merge kernels work in.
 """
 from __future__ import annotations
 
@@ -28,82 +22,80 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-MC = 8  # subspaces contracted per MXU step: onehot chunk (R, MC*256) f32
+from repro.kernels.common import LANES
+
+QROWS = 8  # queries per program
 
 
-def onehot_adc_accumulate(tbl, cod):
-    """Chunked one-hot x table MXU contraction: (m, 256) f32, (R, m) i32 -> (R,).
+def adc_column(table_row, code_col, m: int, rows: int) -> jax.Array:
+    """sum_j table_row(j)[code_col(j)], added in order j = 0..m-1 -> (rows, 1).
 
-    The shared ADC inner loop: also the §4.5 stage of the fused search_step
-    megakernel (repro.kernels.search_step), which must accumulate in exactly
-    this op sequence so the fused and staged paths stay bit-identical. m must
-    already be padded to a multiple of MC (zero table rows are neutral).
+    table_row(j) -> (1, 256) f32 row of subspace j; code_col(j) -> (rows, 1)
+    int32 codes of subspace j. The shared ADC inner loop of this kernel and
+    of the fused search_step megakernel (repro.kernels.search_step).
     """
-    m = tbl.shape[0]
-    R = cod.shape[0]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (rows, 256), 1)
 
-    def chunk(c, acc):
-        tb = jax.lax.dynamic_slice(tbl, (c * MC, 0), (MC, 256))   # (MC, 256)
-        cd = jax.lax.dynamic_slice(cod, (0, c * MC), (R, MC))     # (R, MC)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (R, MC, 256), 2)
-        onehot = (cd[:, :, None] == iota).astype(jnp.float32)     # (R, MC, 256)
-        # contraction (R, MC*256) @ (MC*256,) on the MXU
-        partial = jax.lax.dot_general(
-            onehot.reshape(R, MC * 256),
-            tb.reshape(MC * 256, 1),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )[:, 0]
-        return acc + partial
+    def body(j, acc):
+        hit = code_col(j) == iota
+        term = jnp.where(hit, table_row(j), 0.0)
+        return acc + jnp.sum(term, axis=1, keepdims=True)
 
-    return jax.lax.fori_loop(0, m // MC, chunk, jnp.zeros((R,), jnp.float32))
+    return jax.lax.fori_loop(0, m, body, jnp.zeros((rows, 1), jnp.float32))
 
 
-def _adc_onehot_kernel(table_ref, codes_ref, valid_ref, out_ref):
-    # table (1, m, 256) f32 | codes (1, R, m) i32 | valid (1, R) i32 -> (1, R) f32
-    acc = onehot_adc_accumulate(table_ref[0], codes_ref[0])
-    out_ref[0, :] = jnp.where(valid_ref[0, :] > 0, acc, jnp.inf)
+def column_to_row(col: jax.Array, width: int, off: int = 0) -> jax.Array:
+    """(rows, 1) -> (1, width): col[r] at lane off + r, 0 on every other lane."""
+    rows = col.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+    return jnp.sum(jnp.where(lane == row + off, col, 0.0), axis=0, keepdims=True)
 
 
-def _adc_gather_kernel(table_ref, codes_ref, valid_ref, out_ref):
-    # VPU variant: per-subspace row select via one-hot-free take_along_axis.
-    m = table_ref.shape[1]
-    R = codes_ref.shape[1]
-    tbl = table_ref[0]                                            # (m, 256)
-    cod = codes_ref[0]                                            # (R, m)
-    gathered = jnp.take_along_axis(tbl[None, :, :], cod[:, :, None], axis=2)
-    acc = jnp.sum(gathered[..., 0], axis=1)                       # (R,)
-    out_ref[0, :] = jnp.where(valid_ref[0, :] > 0, acc, jnp.inf)
+def _adc_kernel(table_ref, codes_ref, valid_ref, out_ref):
+    # table (Q, m, 256) f32 | codes (Q, Ra, m) i32 | valid/out (Q, W)
+    Q, Ra, m = codes_ref.shape
+    W = out_ref.shape[1]
+    lane_m = jax.lax.broadcasted_iota(jnp.int32, (Ra, m), 1)
+    for q in range(Q):
+        cod = codes_ref[q]
+        col = adc_column(
+            lambda j: table_ref[q, pl.ds(j, 1), :],
+            lambda j: jnp.sum(jnp.where(lane_m == j, cod, 0), axis=1, keepdims=True),
+            m, Ra,
+        )
+        out_ref[pl.ds(q, 1), :] = column_to_row(col, W)
+    out_ref[...] = jnp.where(valid_ref[...] > 0, out_ref[...], jnp.inf)
 
 
-@functools.partial(jax.jit, static_argnames=("variant", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def adc_pallas(
     table: jax.Array,    # (B, m, 256) f32
     codes: jax.Array,    # (B, R, m) int32
     valid: jax.Array,    # (B, R) bool
     *,
-    variant: str = "onehot",
     interpret: bool = True,
 ) -> jax.Array:
     B, m, _ = table.shape
     R = codes.shape[1]
-    # pad m so the MXU chunk loop divides evenly; zero table rows are neutral
-    pad_m = (-m) % MC
-    if pad_m:
-        table = jnp.pad(table, ((0, 0), (0, pad_m), (0, 0)))
-        codes = jnp.pad(codes, ((0, 0), (0, 0), (0, pad_m)))
-        m += pad_m
-
-    kernel = _adc_onehot_kernel if variant == "onehot" else _adc_gather_kernel
-    return pl.pallas_call(
-        kernel,
-        grid=(B,),
+    pad_b = (-B) % QROWS
+    Ra = R + (-R) % 8
+    W = R + (-R) % LANES
+    table = jnp.pad(table, ((0, pad_b), (0, 0), (0, 0)))
+    codes = jnp.pad(codes.astype(jnp.int32), ((0, pad_b), (0, Ra - R), (0, 0)))
+    valid = jnp.pad(valid.astype(jnp.int32), ((0, pad_b), (0, W - R)))
+    Bp = B + pad_b
+    out = pl.pallas_call(
+        _adc_kernel,
+        grid=(Bp // QROWS,),
         in_specs=[
-            pl.BlockSpec((1, m, 256), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, R, m), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, R), lambda b: (b, 0)),
+            pl.BlockSpec((QROWS, m, 256), lambda b: (b, 0, 0)),
+            pl.BlockSpec((QROWS, Ra, m), lambda b: (b, 0, 0)),
+            pl.BlockSpec((QROWS, W), lambda b: (b, 0)),
         ],
-        out_specs=pl.BlockSpec((1, R), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, R), jnp.float32),
+        out_specs=pl.BlockSpec((QROWS, W), lambda b: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bp, W), jnp.float32),
         interpret=interpret,
-    )(table, codes.astype(jnp.int32), valid.astype(jnp.int32))
+        name="pq_adc",
+    )(table, codes, valid)
+    return out[:B, :R]

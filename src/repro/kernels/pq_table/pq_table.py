@@ -8,9 +8,9 @@ thing into MXU matmuls via the identity
     ||q - c||^2 = ||q||^2 - 2 q.c + ||c||^2
 
 Grid: (m, B/BQ). Each program multiplies a (BQ, dsub) query tile against one
-subspace's (dsub, 256) centroid block -- dsub is zero-padded to a multiple of
+subspace's (256, dsub) centroid block -- dsub is zero-padded to a multiple of
 128 in the wrapper (lane alignment; padding is distance-neutral since both
-operands pad with zeros).
+operands pad with zeros). The centroid norms arrive precomputed as a lane row.
 """
 from __future__ import annotations
 
@@ -20,19 +20,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import LANES
+
 BQ = 8  # queries per program (sublane dim of the MXU operand)
 
 
-def _table_kernel(q_ref, cb_ref, out_ref):
-    # q (BQ, 1, dsub) f32 | cb (1, 256, dsub) f32 -> out (BQ, 1, 256) f32
-    q = q_ref[:, 0, :]                                        # (BQ, dsub)
-    c = cb_ref[0]                                             # (256, dsub)
+def _table_kernel(q_ref, cb_ref, cn_ref, out_ref):
+    # q (1, BQ, dsub) | cb (1, 256, dsub) | cn (1, 1, 256) -> out (1, BQ, 256)
+    q = q_ref[0]
     qn = jnp.sum(q * q, axis=-1, keepdims=True)               # (BQ, 1)
-    cn = jnp.sum(c * c, axis=-1)[None, :]                     # (1, 256)
     qc = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, cb_ref[0], (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )                                                         # (BQ, 256)
-    out_ref[:, 0, :] = qn + cn - 2.0 * qc
+    out_ref[0] = qn + cn_ref[0] - 2.0 * qc
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -43,25 +45,23 @@ def dist_table_pallas(
     interpret: bool = True,
 ) -> jax.Array:
     B, m, dsub = q_sub.shape
-    # lane-align dsub (zero pad: distance-neutral on both operands)
-    pad_d = (-dsub) % 128
-    if pad_d:
-        q_sub = jnp.pad(q_sub, ((0, 0), (0, 0), (0, pad_d)))
-        codebooks = jnp.pad(codebooks, ((0, 0), (0, 0), (0, pad_d)))
-        dsub += pad_d
+    pad_d = (-dsub) % LANES
     pad_b = (-B) % BQ
-    if pad_b:
-        q_sub = jnp.pad(q_sub, ((0, pad_b), (0, 0), (0, 0)))
-
+    q = jnp.pad(q_sub.transpose(1, 0, 2), ((0, 0), (0, pad_b), (0, pad_d)))
+    cb = jnp.pad(codebooks, ((0, 0), (0, 0), (0, pad_d)))
+    cn = jnp.sum(codebooks * codebooks, axis=-1)[:, None, :]  # (m, 1, 256)
+    dp = dsub + pad_d
     out = pl.pallas_call(
         _table_kernel,
         grid=(m, (B + pad_b) // BQ),
         in_specs=[
-            pl.BlockSpec((BQ, 1, dsub), lambda j, b: (b, j, 0)),
-            pl.BlockSpec((1, 256, dsub), lambda j, b: (j, 0, 0)),
+            pl.BlockSpec((1, BQ, dp), lambda j, b: (j, b, 0)),
+            pl.BlockSpec((1, 256, dp), lambda j, b: (j, 0, 0)),
+            pl.BlockSpec((1, 1, 256), lambda j, b: (j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((BQ, 1, 256), lambda j, b: (b, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((B + pad_b, m, 256), jnp.float32),
+        out_specs=pl.BlockSpec((1, BQ, 256), lambda j, b: (j, b, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, B + pad_b, 256), jnp.float32),
         interpret=interpret,
-    )(q_sub, codebooks)
-    return out[:B]
+        name="pq_table",
+    )(q, cb, cn)
+    return out[:, :B].transpose(1, 0, 2)

@@ -2,13 +2,14 @@
 
 After the search converges, every expanded candidate's *full* vector is
 scored against the query exactly. The paper computes each candidate distance
-with a parallel reduction per thread block; on TPU the natural mapping is a
-matvec on the MXU per query tile:
+with a parallel reduction per thread block; here each candidate's
+sum((v - q)^2) is a lane reduction on the VPU, in f32 throughout (no MXU
+pass that could round the operands).
 
-    ||q - v||^2 = ||q||^2 + ||v||^2 - 2 <v, q>
-
-Grid: (B, C/CT). Candidate tiles (CT, d) stream through VMEM while the query
-row (1, d) stays resident; d is zero-padded to a lane multiple in the wrapper.
+Grid: (B/8, C/CT). Candidate tiles (8, CT, d) stream through VMEM while the
+8 query rows stay resident; d is zero-padded to a lane multiple in the
+wrapper (distance-neutral). Each query's (CT, 1) column of distances becomes
+a lane row of the (8, CT) output tile.
 """
 from __future__ import annotations
 
@@ -18,20 +19,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import LANES
+from repro.kernels.pq_adc.pq_adc import column_to_row
+
 CT = 128  # candidates per program
+QROWS = 8  # queries per program
 
 
 def _rerank_kernel(q_ref, v_ref, out_ref):
-    # q (1, d) f32 | v (1, CT, d) f32 -> out (1, CT) f32
-    q = q_ref[0]                                            # (d,)
-    v = v_ref[0]                                            # (CT, d)
-    qn = jnp.sum(q * q)
-    vn = jnp.sum(v * v, axis=-1)                            # (CT,)
-    vq = jax.lax.dot_general(
-        v, q[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]                                                 # (CT,)
-    out_ref[0, :] = qn + vn - 2.0 * vq
+    # q (8, d) f32 | v (8, CT, d) f32 -> out (8, CT) f32
+    for q in range(QROWS):
+        diff = v_ref[q] - q_ref[pl.ds(q, 1), :]                # (CT, d)
+        col = jnp.sum(diff * diff, axis=1, keepdims=True)      # (CT, 1)
+        out_ref[pl.ds(q, 1), :] = column_to_row(col, CT)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -42,24 +42,24 @@ def exact_sq_dists_pallas(
     interpret: bool = True,
 ) -> jax.Array:
     B, C, d = cand_vecs.shape
-    pad_d = (-d) % 128
-    if pad_d:
-        queries = jnp.pad(queries, ((0, 0), (0, pad_d)))
-        cand_vecs = jnp.pad(cand_vecs, ((0, 0), (0, 0), (0, pad_d)))
-        d += pad_d
+    pad_d = (-d) % LANES
     pad_c = (-C) % CT
-    if pad_c:
-        cand_vecs = jnp.pad(cand_vecs, ((0, 0), (0, pad_c), (0, 0)))
-
+    pad_b = (-B) % QROWS
+    queries = jnp.pad(queries.astype(jnp.float32), ((0, pad_b), (0, pad_d)))
+    cand_vecs = jnp.pad(
+        cand_vecs.astype(jnp.float32), ((0, pad_b), (0, pad_c), (0, pad_d))
+    )
+    dp = d + pad_d
     out = pl.pallas_call(
         _rerank_kernel,
-        grid=(B, (C + pad_c) // CT),
+        grid=((B + pad_b) // QROWS, (C + pad_c) // CT),
         in_specs=[
-            pl.BlockSpec((1, d), lambda b, c: (b, 0)),
-            pl.BlockSpec((1, CT, d), lambda b, c: (b, c, 0)),
+            pl.BlockSpec((QROWS, dp), lambda b, c: (b, 0)),
+            pl.BlockSpec((QROWS, CT, dp), lambda b, c: (b, c, 0)),
         ],
-        out_specs=pl.BlockSpec((1, CT), lambda b, c: (b, c)),
-        out_shape=jax.ShapeDtypeStruct((B, C + pad_c), jnp.float32),
+        out_specs=pl.BlockSpec((QROWS, CT), lambda b, c: (b, c)),
+        out_shape=jax.ShapeDtypeStruct((B + pad_b, C + pad_c), jnp.float32),
         interpret=interpret,
-    )(queries.astype(jnp.float32), cand_vecs.astype(jnp.float32))
-    return out[:, :C]
+        name="rerank_l2",
+    )(queries, cand_vecs)
+    return out[:B, :C]

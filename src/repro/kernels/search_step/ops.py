@@ -1,11 +1,12 @@
 """Jitted public wrappers for the fused search_step megakernel.
 
-`fused_step` runs one whole Algorithm-2 iteration (in-kernel code gather +
+`fused_step` runs one whole Algorithm-2 iteration (in-kernel code fetch +
 ADC + sort + §4.6 selection + merge + mark-visited) per grid program;
 `fused_traverse` is the distances-precomputed variant the sharded executors
 use after their owner-ADC psum; `local_adc` is that owner-shard fused
-gather+ADC. All dispatch to compiled Pallas on TPU and interpret elsewhere,
-like every kernel package here.
+fetch+ADC. All dispatch to compiled Pallas on TPU and interpret elsewhere,
+like every kernel package here. The fused kernels read the codes as packed
+lines (`code_lines`), which callers build once per search, outside the loop.
 
 `hbm_candidate_roundtrips_per_hop` / `hbm_intermediate_bytes_per_hop` are the
 analytic HBM-traffic model the in-executor benchmark lane and the tests pin:
@@ -14,14 +15,14 @@ kernel boundary (gathered codes in, ADC distances out/in, sorted tile
 out/in), the fused path reads it exactly once and materialises no
 intermediates.
 
-Beyond VMEM: `resolve_codes_tiling` decides, per codes block, whether the
-fused kernels keep the block VMEM-resident (0) or stream it from HBM through
-the double-buffered DMA pipeline (tile row count > 0). The decision point is
-the VMEM budget (`vmem_budget_bytes`, overridable via the REPRO_VMEM_BUDGET
-env var so tests and benchmarks can force the DMA path on small blocks), or
-an explicit `SearchConfig.codes_tile_rows` -- typically the autotuner's
-winner (`repro.kernels.autotune`). Either way `kernel_mode="fused"` never
-falls back to the staged path.
+Beyond VMEM: `codes_resident` decides, per codes block, whether the
+fused kernels keep the packed lines VMEM-resident or leave them in HBM and
+fetch each candidate's line by DMA. The decision point is the VMEM
+budget (`vmem_budget_bytes`, overridable via the REPRO_VMEM_BUDGET env var so
+tests and benchmarks can force the DMA path on small blocks), or an explicit
+`SearchConfig.codes_tile_rows` > 0, which forces the HBM placement. The DMA
+path fetches rows, not tiles, so the value only selects the placement.
+Either way `kernel_mode="fused"` never falls back to the staged path.
 """
 from __future__ import annotations
 
@@ -34,57 +35,46 @@ from repro.kernels.common import interpret_mode
 
 from .ref import step_ref, traverse_ref
 from .search_step import (
-    fused_step_dma_pallas,
+    LINE_BYTES,
+    code_lines,
     fused_step_pallas,
     fused_traverse_pallas,
-    local_adc_dma_pallas,
+    lines_bytes,
     local_adc_pallas,
 )
 
-# Per-core VMEM the resident fused kernels may assume for the codes block
-# (conservative: real TPU cores have 16-128 MiB and the kernel needs head
-# room for the distance table and worklist tiles).
+# VMEM the resident fused kernels may give the packed codes lines. An
+# assumption, not a reading: v5e cores have 128 MiB of VMEM, and the kernel
+# asks the compiler for twice this (the pipeline double-buffers the block)
+# plus the default 16 MiB scoped limit.
 DEFAULT_VMEM_BUDGET = 16 * 1024 * 1024
-
-# Floor on DMA tile rows: below this the per-tile bookkeeping dominates the
-# copy it hides.
-_MIN_TILE_ROWS = 8
 
 
 def vmem_budget_bytes() -> int:
-    """VMEM budget for the resident codes block (REPRO_VMEM_BUDGET wins)."""
+    """VMEM budget for the resident codes lines (REPRO_VMEM_BUDGET wins)."""
     env = os.environ.get("REPRO_VMEM_BUDGET")
     return int(env) if env else DEFAULT_VMEM_BUDGET
 
 
-def resolve_codes_tiling(n: int, m: int, tile_rows: int = 0) -> int:
-    """How the fused kernels should place an (n, m) u8 codes block.
+def codes_resident(n: int, m: int, tile_rows: int = 0) -> bool:
+    """Whether the fused kernels keep an (n, m) block's code lines in VMEM.
 
-    Returns 0 (keep the block VMEM-resident) or a positive DMA tile row
-    count (stream it from HBM, double-buffered). `tile_rows` > 0 forces an
-    explicit tile size -- the autotuner's knob -- except that a tile
-    covering the whole block degenerates to the resident kernel (a 1-tile
-    pipeline would stream without overlapping anything). `tile_rows` == 0
-    is the auto policy: resident while the block fits `vmem_budget_bytes`,
-    else the largest power-of-two tile whose double buffer fills at most
-    half the budget.
+    `tile_rows` == 0 is the auto policy: resident while the packed lines
+    fit `vmem_budget_bytes`, else in HBM with one row DMA per candidate.
+    `tile_rows` > 0 forces the HBM placement -- the autotuner's knob --
+    unless it covers the whole block.
     """
     if tile_rows < 0:
         raise ValueError(f"tile_rows must be >= 0, got {tile_rows}")
     if tile_rows:
-        return 0 if tile_rows >= n else max(tile_rows, _MIN_TILE_ROWS)
-    budget = vmem_budget_bytes()
-    if n * m <= budget:
-        return 0
-    # 2 tiles (double buffer) x tile_rows x m u8 <= budget / 2.
-    rows = max(budget // (4 * max(m, 1)), _MIN_TILE_ROWS)
-    tile = 1 << (rows.bit_length() - 1)
-    return tile if tile < n else max(_MIN_TILE_ROWS, 1 << ((n - 1).bit_length() - 1))
+        return tile_rows >= n
+    return lines_bytes(n, m) <= vmem_budget_bytes()
 
 
 def fused_step(
     table: jax.Array,
-    codes: jax.Array,
+    lines: jax.Array,
+    n: int,
     wl: Worklist,
     nbrs: jax.Array,
     fresh: jax.Array,
@@ -95,21 +85,14 @@ def fused_step(
 ) -> tuple[Worklist, jax.Array, jax.Array]:
     """One fused iteration: returns (worklist', u_next (B,), active' (B,)).
 
-    `tile_rows` follows `resolve_codes_tiling`: 0 auto-places the codes
-    block (VMEM-resident while it fits the budget, DMA-pipelined beyond),
-    > 0 forces that DMA tile size. Both placements are bit-identical.
+    `lines` = code_lines(codes) of the (n, m) codes block. `tile_rows`
+    follows `codes_resident`; both placements are bit-identical.
     """
-    tr = resolve_codes_tiling(codes.shape[0], codes.shape[1], tile_rows)
-    if tr:
-        d, i, v, u, a = fused_step_dma_pallas(
-            table, codes, nbrs, fresh, wl.dists, wl.ids, wl.visited, active,
-            eager=eager, tile_rows=tr, interpret=interpret_mode(),
-        )
-    else:
-        d, i, v, u, a = fused_step_pallas(
-            table, codes, nbrs, fresh, wl.dists, wl.ids, wl.visited, active,
-            eager=eager, interpret=interpret_mode(),
-        )
+    d, i, v, u, a = fused_step_pallas(
+        table, lines, nbrs, fresh, wl.dists, wl.ids, wl.visited, active,
+        eager=eager, resident=codes_resident(n, table.shape[1], tile_rows),
+        interpret=interpret_mode(),
+    )
     return Worklist(d, i, v), u, a
 
 
@@ -131,27 +114,22 @@ def fused_traverse(
 
 def local_adc(
     table: jax.Array,
-    codes_local: jax.Array,
+    lines_local: jax.Array,
+    n_loc: int,
     rel: jax.Array,
     own: jax.Array,
     *,
     tile_rows: int = 0,
 ) -> jax.Array:
-    """Owner-shard fused gather+ADC: (B, R) contributions, 0 where not owned.
+    """Owner-shard fused fetch+ADC: (B, R) contributions, 0 where not owned.
 
-    `tile_rows` places the shard's codes block exactly like `fused_step`:
-    the sharded fused mode stays beyond-VMEM capable too.
+    `lines_local` = code_lines of the shard's (n_loc, m) codes; `tile_rows`
+    places them exactly like `fused_step`.
     """
-    tr = resolve_codes_tiling(
-        codes_local.shape[0], codes_local.shape[1], tile_rows
-    )
-    if tr:
-        return local_adc_dma_pallas(
-            table, codes_local, rel, own, tile_rows=tr,
-            interpret=interpret_mode(),
-        )
     return local_adc_pallas(
-        table, codes_local, rel, own, interpret=interpret_mode()
+        table, lines_local, rel, own,
+        resident=codes_resident(n_loc, table.shape[1], tile_rows),
+        interpret=interpret_mode(),
     )
 
 
@@ -188,27 +166,27 @@ def hbm_intermediate_bytes_per_hop(
 
 
 def hbm_codes_stream_bytes_per_hop(
-    mode: str, batch: int, n: int, m: int, tile_rows: int = 0
+    mode: str, batch: int, n: int, m: int, R: int, tile_rows: int = 0
 ) -> int:
-    """HBM bytes of *code rows* one hop streams for the beyond-VMEM lane.
+    """HBM bytes of *code lines* one fused hop reads.
 
-    The DMA-pipelined fused kernel reads the full (n, m) u8 block per
-    program (every tile crosses once, double-buffered, overlapped with the
-    ADC); the VMEM-resident fused kernel pays the same logical read when
-    its block is first staged. staged/reference instead gather only the
-    (B, R, m) candidate rows -- already counted by
-    `hbm_intermediate_bytes_per_hop` -- so this lane reports 0 for them:
-    the two estimates partition the traffic, they never double-count.
+    Resident placement: the packed lines block is staged into VMEM once per
+    kernel call, i.e. once per hop. HBM placement: one 512-byte line per
+    candidate lane. staged/reference gather only the (B, R, m) candidate
+    rows -- already counted by `hbm_intermediate_bytes_per_hop` -- so this
+    lane reports 0 for them: the two estimates never double-count.
     """
     if mode != "fused":
         return 0
-    if tile_rows:
-        num_tiles = -(-n // tile_rows)
-        return batch * num_tiles * tile_rows * m
-    return batch * n * m
+    if codes_resident(n, m, tile_rows):
+        return lines_bytes(n, m)
+    return batch * R * LINE_BYTES
 
 
 __all__ = [
+    "code_lines",
+    "codes_resident",
+    "lines_bytes",
     "fused_step",
     "fused_traverse",
     "local_adc",
@@ -217,7 +195,6 @@ __all__ = [
     "hbm_candidate_roundtrips_per_hop",
     "hbm_intermediate_bytes_per_hop",
     "hbm_codes_stream_bytes_per_hop",
-    "resolve_codes_tiling",
     "vmem_budget_bytes",
     "DEFAULT_VMEM_BUDGET",
 ]

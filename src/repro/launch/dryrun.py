@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512 " + os.environ.get("REPRO_EXTRA_XLA_FLAGS", "")
+os.environ["JAX_PLATFORMS"] = "cpu"  # the 512 fake devices are CPU devices
 # ^ MUST precede every other import: jax locks the device count on first init.
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -23,8 +24,6 @@ import time
 import traceback
 
 import jax
-
-from repro.compat import named_shardings, set_mesh
 
 
 COLLECTIVES = (
@@ -90,8 +89,8 @@ def _compile_and_analyze(cfg, shape, mesh):
 
     t0 = time.time()
     step_fn, arg_specs, in_shardings = step_and_specs(cfg, shape, mesh)
-    with set_mesh(mesh):
-        jitted = jax.jit(step_fn, in_shardings=named_shardings(mesh, in_shardings))
+    with jax.set_mesh(mesh):
+        jitted = jax.jit(step_fn, in_shardings=in_shardings)
         lowered = jitted.lower(*arg_specs)
         t_lower = time.time() - t0
         compiled = lowered.compile()

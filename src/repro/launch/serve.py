@@ -20,11 +20,11 @@ def _dryrun_sharded() -> int:
     import os
 
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ["JAX_PLATFORMS"] = "cpu"  # the 512 fake devices are CPU devices
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.compat import set_mesh
     from repro.core import SearchConfig
     from repro.core.distributed import make_sharded_search
     from repro.launch.mesh import make_production_mesh
@@ -43,7 +43,7 @@ def _dryrun_sharded() -> int:
         jax.ShapeDtypeStruct((n, R), jnp.int32),              # adjacency
         jax.ShapeDtypeStruct((n, d), jnp.float32),            # full vectors
     )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*specs)
         compiled = lowered.compile()
     print("sharded ANNS serve step compiled on", mesh.shape)

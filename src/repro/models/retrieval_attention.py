@@ -32,7 +32,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import ambient_mesh, shard_map
+from repro.compat import shard_map
 
 from .layers import apply_rope, truncated_normal_init
 
@@ -102,8 +102,8 @@ def _retrieve_top_l(approx: Array, top_l: int, hier: bool) -> Array:
     fewer collective bytes.
     """
     B, H, S = approx.shape
-    mesh = ambient_mesh()
-    names = tuple(mesh.axis_names) if mesh is not None else ()
+    mesh = jax.sharding.get_abstract_mesh()
+    names = tuple(mesh.axis_names)
     have_model = "model" in names
     NC = mesh.shape["model"] if have_model else 0
     if not (hier and have_model and NC and S % NC == 0 and S // NC >= top_l):

@@ -22,8 +22,11 @@ tracing, not search. `SearchExecutor` is the serving-grade fix (paper §4/§6:
 the pipeline stays resident on the GPU across query batches):
 
   * **Device-resident state.** Codes, codebooks, adjacency and (for the
-    in-memory variants) full vectors are captured once as closure constants of
-    the compiled executable — uploaded at first compile, reused forever.
+    in-memory variants) full vectors are uploaded once and passed to every
+    call of the compiled executable as operands. They are never captured as
+    closure constants: jit embeds a captured array in the program itself,
+    which at deployment size (GBs of adjacency) exhausts host memory at
+    compile time.
   * **One `jax.jit` over stages 1+2+3.** PQ distance-table construction,
     graph traversal and re-ranking fuse into a single executable with the
     query buffer donated, so XLA schedules the whole pipeline end to end.
@@ -149,8 +152,8 @@ class SearchExecutor:
                 f"'base', got {variant!r}"
             )
         self.variant = variant
-        self._codec = codec
-        self._codes = codes
+        self._codebooks = jnp.asarray(codec.codebooks)
+        self._codes = jnp.asarray(codes)
         self._graph = graph
         self._data_dev = data_dev
         self._data_np = data_np
@@ -334,16 +337,17 @@ class SearchExecutor:
         """Trace + lower + compile one executable for `key` (subclass hook)."""
         variant = self.variant
 
-        def pipeline(queries: Array, tombstones: Array | None = None):
+        def pipeline(queries: Array, state, tombstones: Array | None = None):
             # Trace-time side effect: runs once per compiled executable.
             self.trace_counts[key] = self.trace_counts.get(key, 0) + 1
+            codebooks, codes, adjacency, data_dev = state
             tombstone_fn = (
                 None if tombstones is None
                 else searchlib.tombstone_mask_fn(tombstones)
             )
             if variant == "exact":
                 res = searchlib.search_exact(
-                    queries, self._data_dev, self._adjacency,
+                    queries, data_dev, adjacency,
                     self._graph.medoid, cfg, tombstone_fn=tombstone_fn,
                 )
                 # Exact-distance variant skips the re-rank (§5.2): the
@@ -351,22 +355,22 @@ class SearchExecutor:
                 ids = res.worklist.ids[:, :k]
                 dists = res.worklist.dists[:, :k]
             else:
-                table = pqlib.build_dist_table(self._codec, queries)
+                table = pqlib.build_dist_table(pqlib.PQCodec(codebooks), queries)
                 if variant == "inmem":
                     res = searchlib.search_inmem(
-                        queries, table, self._codes, self._adjacency,
+                        queries, table, codes, adjacency,
                         self._graph.medoid, cfg, tombstone_fn=tombstone_fn,
                     )
                 else:
                     neighbor_fn, prefetch_fn = self._exchange
                     res = searchlib.search_base(
-                        queries, table, self._codes, self._adjacency_np,
+                        queries, table, codes, self._adjacency_np,
                         self._graph.medoid, cfg,
                         neighbor_fn=neighbor_fn, prefetch_fn=prefetch_fn,
                         tombstone_fn=tombstone_fn,
                     )
                 if rerank:
-                    if variant == "base" or self._data_dev is None:
+                    if variant == "base" or data_dev is None:
                         ids, dists = rr.rerank(
                             queries, res.history_ids, k,
                             data_np=self._data_np,
@@ -375,7 +379,7 @@ class SearchExecutor:
                     else:
                         ids, dists = rr.rerank(
                             queries, res.history_ids, k,
-                            data=self._data_dev,
+                            data=data_dev,
                             use_kernels=cfg.uses_kernels(),
                         )
                 else:
@@ -385,16 +389,24 @@ class SearchExecutor:
 
         spec = jax.ShapeDtypeStruct((bucket, d), jnp.float32)
         if not self._with_tombstones:
-            return jax.jit(pipeline, donate_argnums=0).lower(spec).compile()
+            return (
+                jax.jit(pipeline, donate_argnums=0)
+                .lower(spec, self._state())
+                .compile()
+            )
         # Tombstone-capable executable: the bitmap is a true operand (never a
         # captured constant), so deletes update it without retracing; only
         # the query buffer stays donated.
         tomb_spec = jax.ShapeDtypeStruct((self._tombstone_len,), jnp.bool_)
         return (
             jax.jit(pipeline, donate_argnums=0)
-            .lower(spec, tomb_spec)
+            .lower(spec, self._state(), tomb_spec)
             .compile()
         )
+
+    def _state(self) -> tuple:
+        """The device index state every executable call takes as operands."""
+        return (self._codebooks, self._codes, self._adjacency, self._data_dev)
 
     # ----------------------------------------------------- subclass hooks
     # ShardedSearchExecutor overrides these three to place queries on the
@@ -423,8 +435,8 @@ class SearchExecutor:
 
     def _run(self, compiled, q_dev: Array, tomb_dev: Array | None = None):
         if tomb_dev is None:
-            return compiled(q_dev)
-        return compiled(q_dev, tomb_dev)
+            return compiled(q_dev, self._state())
+        return compiled(q_dev, self._state(), tomb_dev)
 
     # ------------------------------------------------------------ accounting
     def _hot_cache_fields(self, host_rows_in: int) -> dict:
@@ -545,10 +557,10 @@ class SearchExecutor:
             # the dispatch with a jax.profiler annotation so device
             # timelines carry the same names as our Chrome trace. Host-side
             # only: the compiled program is the same object either way.
-            _, m, n_block = self.autotune_shape()
+            R, m, n_block = self.autotune_shape()
             tel.profiler.set_kernel_info(
                 kernel_mode=cfg.kernel_mode, batch=bucket, n=n_block, m=m,
-                tile_rows=cfg.codes_tile_rows,
+                R=R, tile_rows=cfg.codes_tile_rows,
             )
             with tel.profiler.annotate(
                     f"bang_dispatch:{cfg.kernel_mode}:b{bucket}"):

@@ -65,12 +65,13 @@ class HopProfiler:
             self._cache_hits.append(int(cache_hit_lanes))
 
     def set_kernel_info(self, *, kernel_mode: str, batch: int, n: int,
-                        m: int, tile_rows: int = 0) -> None:
+                        m: int, R: int, tile_rows: int = 0) -> None:
         """Stamp dispatch-time kernel metadata for codes-stream accounting."""
         with self._lock:
             self._kernel_info = {
                 "kernel_mode": kernel_mode, "batch": int(batch),
-                "n": int(n), "m": int(m), "tile_rows": int(tile_rows),
+                "n": int(n), "m": int(m), "R": int(R),
+                "tile_rows": int(tile_rows),
             }
 
     # ----------------------------------------------------------- annotations
@@ -132,7 +133,7 @@ class HopProfiler:
 
             per_hop = hbm_codes_stream_bytes_per_hop(
                 info["kernel_mode"], info["batch"], info["n"], info["m"],
-                tile_rows=info["tile_rows"],
+                info["R"], tile_rows=info["tile_rows"],
             )
             out["codes_stream_bytes_per_hop"] = per_hop
             out["codes_stream_bytes_total"] = per_hop * n

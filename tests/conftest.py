@@ -1,10 +1,6 @@
 """Test fixtures. NOTE: never set xla_force_host_platform_device_count here --
 smoke tests must see exactly 1 device; multi-device tests spawn subprocesses.
 """
-import os
-
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
-
 import numpy as np
 import pytest
 

@@ -129,13 +129,11 @@ def test_apply_replaces_only_on_winner():
 
 
 def test_default_tile_candidates(monkeypatch):
-    # Resident block: no tile axis to sweep.
-    assert at.default_tile_candidates(1200, 8) == (0,)
-    # Beyond the (forced) budget: auto tile and pow2 neighbours join.
+    # A block that fits VMEM sweeps both placements: resident, forced HBM.
+    assert at.default_tile_candidates(1200, 8) == (0, 8)
+    # Beyond the (forced) budget the auto placement already is HBM.
     monkeypatch.setenv("REPRO_VMEM_BUDGET", "2048")
-    cands = at.default_tile_candidates(1200, 8)
-    assert 0 in cands and len(cands) >= 2
-    assert all(c == 0 or 8 <= c < 1200 for c in cands)
+    assert at.default_tile_candidates(1200, 8) == (0,)
 
 
 def test_autotune_executor_sweep_records_winner(small_ann_index, rng):
@@ -155,16 +153,3 @@ def test_autotune_executor_sweep_records_winner(small_ann_index, rng):
     assert w["eager"] is True and w["codes_tile_rows"] in (0, 64)
     assert w["per_hop_us"] > 0
     assert ex._autotune is None                       # restored, not leaked
-
-
-def test_setup_xla_flags_idempotent_and_caller_wins(monkeypatch):
-    monkeypatch.delenv("XLA_FLAGS", raising=False)
-    v1 = at.setup_xla_flags()
-    assert all(f in v1.split() for f in at.LATENCY_HIDING_XLA_FLAGS)
-    assert at.setup_xla_flags() == v1                 # idempotent
-    # An explicit caller value for the same flag is never overridden.
-    ours = "--xla_gpu_enable_latency_hiding_scheduler=false"
-    monkeypatch.setenv("XLA_FLAGS", ours)
-    v2 = at.setup_xla_flags().split()
-    assert ours in v2
-    assert "--xla_gpu_enable_latency_hiding_scheduler=true" not in v2

@@ -78,8 +78,8 @@ def test_partitioning_rules_divisibility():
     import os
     # production mesh needs 256 devices; use an abstract mesh instead
     from jax.sharding import PartitionSpec as P
-    from repro.compat import abstract_mesh
-    mesh = abstract_mesh((("data", 16), ("model", 16)))
+    from jax.sharding import AbstractMesh
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     cfg = configs.get("granite-3-2b")
     specs = param_pspecs(param_specs(cfg), mesh)
     assert specs["embed"] == P(None, "data")      # vocab 49155 odd -> replicated

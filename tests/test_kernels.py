@@ -22,14 +22,13 @@ KERNEL_MODES = ("reference", "staged", "fused")
 
 
 @pytest.mark.parametrize("B,R,m", [(1, 4, 4), (3, 17, 9), (8, 64, 74), (5, 31, 16)])
-@pytest.mark.parametrize("variant", ["onehot", "gather"])
-def test_pq_adc(B, R, m, variant, rng):
+def test_pq_adc(B, R, m, rng):
     from repro.kernels.pq_adc import ops
 
     table = jnp.asarray(rng.standard_normal((B, m, 256)).astype(np.float32) ** 2)
     codes = jnp.asarray(rng.integers(0, 256, (B, R, m)).astype(np.int32))
     valid = jnp.asarray(rng.random((B, R)) > 0.25)
-    out = ops.adc(table, codes, valid, variant=variant)
+    out = ops.adc(table, codes, valid)
     ref = ops.adc_ref(table, codes, valid)
     fin = np.isfinite(np.asarray(ref))
     np.testing.assert_allclose(np.asarray(out)[fin], np.asarray(ref)[fin], rtol=1e-5)
@@ -126,7 +125,8 @@ def _assert_step_matches_oracle(table, codes, nbrs, fresh, wl, active, eager,
                                 tile_rows=0):
     from repro.kernels.search_step import ops
 
-    wl2, u, a = ops.fused_step(table, codes, wl, nbrs, fresh, active,
+    wl2, u, a = ops.fused_step(table, ops.code_lines(codes), codes.shape[0],
+                               wl, nbrs, fresh, active,
                                eager=eager, tile_rows=tile_rows)
     rd, ri, rv, ru, ra = ops.step_ref(
         table, codes, nbrs, fresh, wl.dists, wl.ids, wl.visited, active,
@@ -294,71 +294,74 @@ def test_bench_kernel_row_json_schema():
 def test_resolve_codes_tiling_policy(monkeypatch):
     from repro.kernels.search_step import ops
 
-    # Resident while the block fits the default budget.
-    assert ops.resolve_codes_tiling(1200, 8) == 0
-    # Explicit tile: the autotuner's knob, floored at the minimum; a tile
-    # covering the whole block degenerates to the resident kernel.
-    assert ops.resolve_codes_tiling(1200, 8, 64) == 64
-    assert ops.resolve_codes_tiling(1200, 8, 3) == 8
-    assert ops.resolve_codes_tiling(1200, 8, 1200) == 0
-    assert ops.resolve_codes_tiling(1200, 8, 5000) == 0
+    # Resident while the packed lines fit the default budget.
+    assert ops.codes_resident(1200, 8)
+    # Explicit tile_rows: the autotuner's knob forces the HBM placement,
+    # unless it covers the whole block.
+    assert not ops.codes_resident(1200, 8, 64)
+    assert not ops.codes_resident(1200, 8, 3)
+    assert ops.codes_resident(1200, 8, 1200)
+    assert ops.codes_resident(1200, 8, 5000)
     with pytest.raises(ValueError, match="tile_rows"):
-        ops.resolve_codes_tiling(1200, 8, -1)
-    # Auto beyond the budget: a power-of-two tile whose double buffer fits
-    # half the (env-forced) budget, never the whole block.
+        ops.codes_resident(1200, 8, -1)
+    # Auto beyond the (env-forced) budget: HBM. 1200 rows of m = 8 pack
+    # into 19 lines of 512 bytes.
     monkeypatch.setenv("REPRO_VMEM_BUDGET", "2048")
-    tile = ops.resolve_codes_tiling(1200, 8)
-    assert tile > 0 and tile & (tile - 1) == 0 and tile < 1200
-    assert 2 * tile * 8 <= 2048
     assert ops.vmem_budget_bytes() == 2048
+    assert ops.lines_bytes(1200, 8) == 19 * 512
+    assert not ops.codes_resident(1200, 8)
+    assert ops.codes_resident(30, 8)
 
 
-@pytest.mark.parametrize("tile_rows", [8, 16, 64, 100, 119])
+@pytest.mark.parametrize("n", [8, 16, 64, 100, 119])
 @pytest.mark.parametrize("eager", [True, False])
-def test_fused_step_dma_matches_resident(tile_rows, eager, rng):
-    """The DMA-pipelined megakernel is bit-identical to the VMEM-resident
-    one (and hence the ref.py oracle) for divisor, non-divisor and
-    near-whole-block tile sizes -- every candidate lane's distance comes
-    from its single owning tile, so no partial sums ever merge."""
+def test_fused_step_dma_matches_resident(n, eager, rng):
+    """The HBM-placed megakernel (one row DMA per candidate) is bit-identical
+    to the VMEM-resident one (and hence the ref.py oracle) for blocks that
+    fill, overfill and underfill their packed code lines."""
     from repro.kernels.common import interpret_mode
     from repro.kernels.search_step.search_step import (
-        fused_step_dma_pallas, fused_step_pallas,
+        code_lines, fused_step_pallas,
     )
 
     table, codes, nbrs, fresh, wl, active = _random_step_inputs(
-        rng, 4, 17, 24, 9, 120
+        rng, 4, 17, 24, 9, n
     )
-    res = fused_step_pallas(
-        table, codes, nbrs, fresh, wl.dists, wl.ids, wl.visited, active,
-        eager=eager, interpret=interpret_mode(),
-    )
-    dma = fused_step_dma_pallas(
-        table, codes, nbrs, fresh, wl.dists, wl.ids, wl.visited, active,
-        eager=eager, tile_rows=tile_rows, interpret=interpret_mode(),
+    res, dma = (
+        fused_step_pallas(
+            table, code_lines(codes), nbrs, fresh, wl.dists, wl.ids,
+            wl.visited, active, eager=eager, resident=resident,
+            interpret=interpret_mode(),
+        )
+        for resident in (True, False)
     )
     for a, b in zip(res, dma):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("tile_rows", [8, 32, 100])
-def test_local_adc_dma_matches_resident(tile_rows, rng):
-    """Sharded owner-shard fused gather+ADC: DMA placement bit-identical."""
+@pytest.mark.parametrize("m", [4, 9, 32])
+def test_local_adc_dma_matches_resident(m, rng):
+    """Sharded owner-shard fused fetch+ADC: HBM placement bit-identical to
+    the resident one and to the oracle's owned rows."""
     from repro.kernels.common import interpret_mode
+    from repro.kernels.pq_adc.ref import adc_ref
     from repro.kernels.search_step.search_step import (
-        local_adc_dma_pallas, local_adc_pallas,
+        code_lines, local_adc_pallas,
     )
 
-    B, R, m, n_loc = 5, 13, 9, 120
+    B, R, n_loc = 5, 13, 120
     table = jnp.asarray(rng.integers(0, 1000, (B, m, 256)).astype(np.float32))
     codes = jnp.asarray(rng.integers(0, 256, (n_loc, m)).astype(np.uint8))
     rel = jnp.asarray(rng.integers(0, n_loc, (B, R)).astype(np.int32))
     own = jnp.asarray(rng.random((B, R)) > 0.4)
-    res = local_adc_pallas(table, codes, rel, own, interpret=interpret_mode())
-    dma = local_adc_dma_pallas(
-        table, codes, rel, own, tile_rows=tile_rows,
-        interpret=interpret_mode(),
+    res, dma = (
+        local_adc_pallas(table, code_lines(codes), rel, own,
+                         resident=resident, interpret=interpret_mode())
+        for resident in (True, False)
     )
     np.testing.assert_array_equal(np.asarray(res), np.asarray(dma))
+    ref = jnp.where(own, adc_ref(table, codes[rel], own), 0.0)
+    np.testing.assert_array_equal(np.asarray(res), np.asarray(ref))
 
 
 @pytest.mark.parametrize("tile_rows", [0, 16, 90])
@@ -373,7 +376,7 @@ def test_fused_step_tile_rows_dispatch_bit_exact(tile_rows, eager, rng,
     )
     from repro.kernels.search_step import ops
 
-    assert ops.resolve_codes_tiling(120, 9, tile_rows) > 0
+    assert not ops.codes_resident(120, 9, tile_rows)
     _assert_step_matches_oracle(table, codes, nbrs, fresh, wl, active, eager,
                                 tile_rows=tile_rows)
 
@@ -394,7 +397,7 @@ def test_beyond_vmem_executor_parity(small_ann_index, variant, rng,
     monkeypatch.setenv("REPRO_VMEM_BUDGET", "2048")
     data, idx = small_ann_index
     n, m = idx.codes.shape
-    assert n * m > 2048 and step_ops.resolve_codes_tiling(n, m) > 0
+    assert not step_ops.codes_resident(n, m)
     queries = rng.standard_normal((6, data.shape[1])).astype(np.float32)
     cfg = SearchConfig(t=16, bloom_z=4096)
     out = {}
@@ -417,21 +420,21 @@ def test_beyond_vmem_executor_parity(small_ann_index, variant, rng,
 
 
 def test_hbm_codes_stream_accounting():
-    """The DMA lane's analytic codes-stream traffic: fused streams the
-    padded block once per hop per query, other modes report 0 (their codes
-    traffic is inside the candidate-roundtrip/intermediate terms)."""
+    """The fused lane's analytic codes traffic: the resident block is staged
+    once per hop, the HBM placement reads one 512-byte line per candidate;
+    other modes report 0 (their codes traffic is inside the
+    candidate-roundtrip/intermediate terms)."""
     from repro.kernels.search_step import ops
 
-    B, n, m = 16, 8000, 16
-    assert ops.hbm_codes_stream_bytes_per_hop("staged", B, n, m, 64) == 0
-    assert ops.hbm_codes_stream_bytes_per_hop("reference", B, n, m, 64) == 0
-    # Resident fused block: the same logical whole-block read, unpadded.
-    assert ops.hbm_codes_stream_bytes_per_hop("fused", B, n, m, 0) == B * n * m
-    streamed = ops.hbm_codes_stream_bytes_per_hop("fused", B, n, m, 64)
-    num_tiles = -(-n // 64)
-    assert streamed == B * num_tiles * 64 * m
-    # Padding only: the DMA stream never exceeds one extra tile per program.
-    assert B * n * m <= streamed <= B * (n + 64) * m
+    B, n, m, R = 16, 8000, 16, 32
+    assert ops.hbm_codes_stream_bytes_per_hop("staged", B, n, m, R, 64) == 0
+    assert ops.hbm_codes_stream_bytes_per_hop("reference", B, n, m, R, 64) == 0
+    # Resident lines: 32 rows of 16 codes per 512-byte line, staged once.
+    assert ops.hbm_codes_stream_bytes_per_hop("fused", B, n, m, R, 0) == n * m
+    # HBM placement: independent of n, one line per candidate lane.
+    assert ops.hbm_codes_stream_bytes_per_hop("fused", B, n, m, R, 64) == (
+        B * R * 512
+    )
 
 
 def test_bench_beyond_vmem_row_json_schema():

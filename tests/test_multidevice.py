@@ -30,7 +30,7 @@ def test_sharded_search_matches_single_device():
         """
 import numpy as np, jax
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh, set_mesh
+from repro.compat import make_mesh
 from repro.core import BangIndex, SearchConfig, brute_force_knn, recall_at_k
 from repro.core.distributed import make_sharded_search, pad_to_multiple
 
@@ -45,7 +45,7 @@ adj = pad_to_multiple(idx.graph.adjacency, 2, -1)
 codes = pad_to_multiple(np.asarray(idx.codes), 2, 0)
 dat = pad_to_multiple(data, 2, 1e9)
 fn = make_sharded_search(mesh, idx.graph.medoid, k, cfg)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     args = [
         jax.device_put(queries, NamedSharding(mesh, P("data", None))),
         jax.device_put(np.asarray(idx.codec.codebooks), NamedSharding(mesh, P())),
@@ -69,7 +69,6 @@ def test_reduced_arch_train_step_on_mesh():
 import numpy as np, jax, jax.numpy as jnp
 import dataclasses
 import repro.configs as configs
-from repro.compat import named_shardings, set_mesh
 from repro.configs.base import ShapeSpec
 from repro.launch.specs import step_and_specs
 from repro.launch.mesh import make_test_mesh
@@ -81,8 +80,8 @@ cfg = configs.get("glm4-9b").reduced(d_model=128, n_heads=8, n_kv_heads=2, head_
 shape = ShapeSpec("t", "train", 64, 8)
 mesh = make_test_mesh((4, 2), ("data", "model"))
 step, specs, shardings = step_and_specs(cfg, shape, mesh)
-with set_mesh(mesh):
-    jitted = jax.jit(step, in_shardings=named_shardings(mesh, shardings))
+with jax.set_mesh(mesh):
+    jitted = jax.jit(step, in_shardings=shardings)
     # materialize real inputs placed with the expected shardings
     def mk(s, spec):
         host = (_np.zeros(s.shape, "int32") if s.dtype == jnp.int32

@@ -42,6 +42,19 @@ def test_encode_is_argmin(rng):
             assert codes[i, j] == np.argmin(d2)
 
 
+@given(st.integers(0, 2**31 - 1))
+@settings(max_examples=25, deadline=None)
+def test_first_argmin_matches_argmin(seed):
+    """first_argmin == argmin, first index on ties, batched over leading axes."""
+    from repro.core.kmeans import first_argmin
+
+    r = np.random.default_rng(seed)
+    x = r.integers(0, 6, (3, 17, 256)).astype(np.float32)  # many ties
+    np.testing.assert_array_equal(
+        np.asarray(first_argmin(jnp.asarray(x))), np.argmin(x, axis=-1)
+    )
+
+
 def test_training_reduces_quantization_error(rng):
     from repro.data import gaussian_mixture
 

@@ -255,7 +255,7 @@ def test_hop_profiler_summary_and_bounds():
     assert s["kernel_info"] is None
     assert s["codes_stream_bytes_per_hop"] is None
 
-    prof.set_kernel_info(kernel_mode="reference", batch=8, n=1000, m=8)
+    prof.set_kernel_info(kernel_mode="reference", batch=8, n=1000, m=8, R=16)
     s = prof.summary()
     assert s["kernel_info"]["kernel_mode"] == "reference"
     per_hop = s["codes_stream_bytes_per_hop"]

@@ -18,7 +18,10 @@ from repro.core.worklist import (
     worklist_init,
 )
 
-finite_f32 = st.floats(-1e6, 1e6, width=32, allow_nan=False)
+# XLA flushes subnormals to zero and numpy does not, so a subnormal key
+# would compare differently on the two sides.
+finite_f32 = st.floats(-1e6, 1e6, width=32, allow_nan=False,
+                       allow_subnormal=False)
 
 
 @settings(max_examples=40, deadline=None)
