@@ -206,16 +206,22 @@ traced programs, their cache keys and their results are byte-identical.
   exactly ONE terminal event -- a 'request' complete span (args: rid,
   outcome=served|cache_hit), a 'request_shed' instant or a
   'request_expired' instant; batch phases appear as 'admission',
-  'dispatch', 'device' and 'compile' complete spans; host gathers as
-  'gather' / 'prefetch_gather' spans (args: hop, rows, mode); mutation
-  as 'consolidate' spans + 'generation_swap' instants; resilience
-  transitions as 'failover', 'partition_down', 'recover', 'degraded'
-  and 'deadline_hit' instants.
+  'dispatch', 'device' and 'compile' complete spans, each drain as a
+  'drain' span with a 'gc' span per Python collection inside it; host
+  gathers as 'gather' / 'prefetch_gather' spans (args: hop, rows, mode)
+  and the Base re-rank's vector gathers as 'rerank_gather' spans (track
+  'rerank', args: rows); mutation as 'consolidate' spans +
+  'generation_swap' instants; resilience transitions as 'failover',
+  'partition_down', 'recover', 'degraded' and 'deadline_hit' instants.
+  While a jax.profiler trace is being captured, the spans that wrap work
+  (gather, dispatch, compile, drain, rerank_gather, gc) also appear on
+  its host plane as 'bang.<span>' events, on the device ops' clock, and
+  every device op of the search carries its stage scope (bang.table,
+  bang.fetch, bang.bloom, bang.step, bang.history, bang.rerank).
 
   hop profiler (--profile-hops): per-hop host-gather wall time, frontier
   occupancy, cache-hit lanes and the modeled PQ-codes-stream bytes/hop,
-  printed as a summary table after the drain (plus jax.profiler trace
-  annotations when a device profile is being captured).
+  printed as a summary table after the drain.
 
   flight recorder (used by the benches/tests; see
   repro.runtime.telemetry.flightrecorder): bounded in-memory ring of

@@ -9,8 +9,12 @@ In BANG Base the full vectors live on the host and only the candidates' rows
 cross the link ("only full vectors of selected nodes are sent to GPU") -- here
 that is a pure_callback gather. In-memory variants gather from device HBM.
 The exact-L2 + top-k math has a Pallas fast path (repro/kernels/rerank_l2).
+The whole stage runs under the `bang.rerank` named scope (see
+`core.search.STAGES`).
 """
 from __future__ import annotations
+
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -31,8 +35,14 @@ Array = jax.Array
 _GATHER_CHUNK_BYTES = 64 * 1024
 
 
+def gather_chunk_rows(d: int) -> int:
+    """Rows of width `d` per host-gather callback (see _GATHER_CHUNK_BYTES)."""
+    return max(1, _GATHER_CHUNK_BYTES // (d * 4))
+
+
 def gather_host_vectors(
-    data_np: np.ndarray, ids: Array, *, chunk_rows: int | None = None
+    data_np: np.ndarray, ids: Array, *, chunk_rows: int | None = None,
+    tracer: Callable | None = None,
 ) -> Array:
     """Host-side candidate-vector service (BANG Base link traffic).
 
@@ -40,15 +50,29 @@ def gather_host_vectors(
     than one bulk transfer, mirroring the paper's batched candidate shipping
     (§4.9) and keeping each result under XLA:CPU's parallel-consumer
     threshold (see _GATHER_CHUNK_BYTES).
+
+    `tracer` returns the telemetry `Tracer` to record into, or None. Each
+    callback body calls it, so attaching a tracer later changes no traced
+    program; while one is attached, every callback records a
+    `rerank_gather` span (args: rows).
     """
     d = data_np.shape[1]
 
-    def host_gather(idx: np.ndarray) -> np.ndarray:
+    def gather(idx: np.ndarray) -> np.ndarray:
         safe = np.where(idx == np.int32(2**31 - 1), 0, idx)
         return np.ascontiguousarray(data_np[safe], dtype=np.float32)
 
+    host_gather = gather
+    if tracer is not None:
+        def host_gather(idx: np.ndarray) -> np.ndarray:
+            tr = tracer()
+            if tr is None:
+                return gather(idx)
+            with tr.span("rerank_gather", track="rerank", rows=int(idx.size)):
+                return gather(idx)
+
     if chunk_rows is None:
-        chunk_rows = max(1, _GATHER_CHUNK_BYTES // (d * 4))
+        chunk_rows = gather_chunk_rows(d)
     flat = ids.reshape(-1)
     total = flat.shape[0]
     if total <= chunk_rows:
@@ -101,16 +125,20 @@ def rerank(
     data: Array | None = None,
     data_np: np.ndarray | None = None,
     use_kernels: bool = False,
+    tracer: Callable | None = None,
 ) -> tuple[Array, Array]:
     """Full re-rank stage: gather candidate vectors, exact top-k.
 
     Exactly one of data (device) / data_np (host) must be provided. Host
-    gathers are transparently chunked (see gather_host_vectors).
+    gathers are transparently chunked (see gather_host_vectors, which also
+    says what `tracer` does).
     """
     assert (data is None) != (data_np is None)
-    if data is not None:
-        safe = jnp.where(history_ids == INVALID_ID, 0, history_ids)
-        vecs = data[safe].astype(jnp.float32)
-    else:
-        vecs = gather_host_vectors(data_np, history_ids)
-    return exact_topk(queries, vecs, history_ids, k, use_kernels=use_kernels)
+    with jax.named_scope("bang.rerank"):
+        if data is not None:
+            safe = jnp.where(history_ids == INVALID_ID, 0, history_ids)
+            vecs = data[safe].astype(jnp.float32)
+        else:
+            vecs = gather_host_vectors(data_np, history_ids, tracer=tracer)
+        return exact_topk(queries, vecs, history_ids, k,
+                          use_kernels=use_kernels)

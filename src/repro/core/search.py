@@ -44,6 +44,13 @@ Variants (paper §5):
 graph rows device-sharded; "sharded-base": graph rows in host RAM behind
 per-shard callbacks) by passing its own StepFn built on sharded
 neighbour/distance collectives.
+
+Stage scopes: each stage runs under a `jax.named_scope` from `STAGES`, the
+same in every kernel mode, so every op of the compiled program carries its
+stage in its `op_name` metadata (`SearchExecutor.stage_map` reads it back
+for the device trace). Scopes are metadata only: the computation is the
+same with or without them. `ReferenceStep` adds `adc` and `merge`
+sub-scopes under `bang.step`; the fused megakernel cannot have them.
 """
 from __future__ import annotations
 
@@ -71,6 +78,13 @@ from .worklist import (
 Array = jax.Array
 
 KERNEL_MODES = ("reference", "staged", "fused")
+
+# Algorithm 2's stages as named scopes: the PQ distance table (stage 1), the
+# neighbour fetch (host exchange or device gather), the bloom filter with the
+# validity mask it consumes, the StepFn (distances, sort, selection, merge),
+# the history update, and the re-rank (stage 3).
+STAGES = ("bang.table", "bang.fetch", "bang.bloom", "bang.step",
+          "bang.history", "bang.rerank")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -237,7 +251,8 @@ class ReferenceStep(StepFn):
         prefetch_fn: "PrefetchFn | None" = None,
     ) -> tuple[Worklist, Array, Array, Array | None]:
         # 3. PQ (or exact) distances for fresh neighbours.
-        d = self.distance_fn(nbrs, fresh)
+        with jax.named_scope("adc"):
+            d = self.distance_fn(nbrs, fresh)
         cand_ids = jnp.where(fresh, nbrs, INVALID_ID)
 
         # 4. Sort the candidate list (parallel merge sort / bitonic kernel).
@@ -265,9 +280,11 @@ class ReferenceStep(StepFn):
                 # convergence masking below may still retire a lane, and
                 # collect() inline-gathers any mismatched lane.
                 tok = prefetch_fn(u_next)
-            wl = self._merge(wl, sd, si)
+            with jax.named_scope("merge"):
+                wl = self._merge(wl, sd, si)
         else:
-            wl = self._merge(wl, sd, si)
+            with jax.named_scope("merge"):
+                wl = self._merge(wl, sd, si)
             u_next, found = first_unvisited(wl)
 
         active = active & found
@@ -469,6 +486,16 @@ def host_neighbor_fn(adjacency_np: np.ndarray) -> NeighborFn:
     return fn
 
 
+def _scoped(name: str, fn: Callable) -> Callable:
+    """`fn` traced under the named scope `name`."""
+
+    def wrapped(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+
+    return wrapped
+
+
 def bang_search(
     queries: Array,
     *,
@@ -506,21 +533,29 @@ def bang_search(
         if distance_fn is None:
             raise ValueError("bang_search needs distance_fn or step_fn")
         step_fn = make_step_fn(cfg, distance_fn)
+    if prefetch_fn is not None:
+        # The prefetch is exchange work wherever it is issued, including from
+        # inside the step (the §4.6 seam): innermost scope names the stage.
+        prefetch_fn = _scoped("bang.fetch", prefetch_fn)
     B = queries.shape[0]
     t, C = cfg.t, cfg.iters()
 
     # --- Initialisation: 𝓛 = {medoid}, bloom = {medoid} (Algorithm 2 line 2).
     med = jnp.full((B,), medoid, jnp.int32)
     med_valid = jnp.ones((B, 1), jnp.bool_)
-    med_d = step_fn.init_dists(med[:, None], med_valid)[:, 0]   # (B,)
-    wl0 = worklist_init(B, t)
-    wl0 = Worklist(
-        dists=wl0.dists.at[:, 0].set(med_d),
-        ids=wl0.ids.at[:, 0].set(med),
-        visited=wl0.visited.at[:, 0].set(True),   # medoid is the first expansion
-    )
-    filt0 = bloomlib.bloom_set(bloomlib.bloom_init(B, cfg.bloom_z), med[:, None])
-    hist0 = jnp.full((B, C), INVALID_ID, jnp.int32).at[:, 0].set(med)
+    with jax.named_scope("bang.step"):
+        med_d = step_fn.init_dists(med[:, None], med_valid)[:, 0]   # (B,)
+        wl0 = worklist_init(B, t)
+        wl0 = Worklist(
+            dists=wl0.dists.at[:, 0].set(med_d),
+            ids=wl0.ids.at[:, 0].set(med),
+            visited=wl0.visited.at[:, 0].set(True),  # medoid: first expansion
+        )
+    with jax.named_scope("bang.bloom"):
+        filt0 = bloomlib.bloom_set(
+            bloomlib.bloom_init(B, cfg.bloom_z), med[:, None])
+    with jax.named_scope("bang.history"):
+        hist0 = jnp.full((B, C), INVALID_ID, jnp.int32).at[:, 0].set(med)
     # Warm-start ticket: the medoid fetch of iteration 0 redeems a prefetch
     # issued before the loop, so even the first hop's gather can overlap the
     # worklist/bloom initialisation above.
@@ -548,46 +583,51 @@ def bang_search(
         #    so this gather has no data dependency on the previous merge.
         #    With the hostio prefetched exchange the overlap is real: the
         #    ticket in the loop state redeems the gather issued last hop.
-        if prefetch_fn is None:
-            nbrs = neighbor_fn(s.u)                               # (B, R)
-        else:
-            nbrs = neighbor_fn(s.u, s.tok)                        # (B, R)
-        # The (nbrs >= 0) validity check is also the degraded-serving seam:
-        # unfetchable lanes (host partition down, "mask" mode) arrive as
-        # all -1 rows from the exchange and are dropped here exactly like
-        # adjacency padding -- no extra operand, no retrace.
-        valid = (nbrs >= 0) & s.active[:, None]
-        if tombstone_fn is not None:
-            # Streaming mutability (§4.6 selection / worklist-merge masks):
-            # tombstoned neighbours become padding lanes right here, before
-            # the bloom filter and the StepFn, so every kernel mode scores
-            # them +inf and they never enter 𝓛 or the final top-k.
-            valid = valid & ~tombstone_fn(nbrs)
+        with jax.named_scope("bang.fetch"):
+            if prefetch_fn is None:
+                nbrs = neighbor_fn(s.u)                           # (B, R)
+            else:
+                nbrs = neighbor_fn(s.u, s.tok)                    # (B, R)
+        with jax.named_scope("bang.bloom"):
+            # The (nbrs >= 0) validity check is also the degraded-serving
+            # seam: unfetchable lanes (host partition down, "mask" mode)
+            # arrive as all -1 rows from the exchange and are dropped here
+            # exactly like adjacency padding -- no extra operand, no retrace.
+            valid = (nbrs >= 0) & s.active[:, None]
+            if tombstone_fn is not None:
+                # Streaming mutability (§4.6 selection / worklist-merge
+                # masks): tombstoned neighbours become padding lanes right
+                # here, before the bloom filter and the StepFn, so every
+                # kernel mode scores them +inf and they never enter 𝓛 or
+                # the final top-k.
+                valid = valid & ~tombstone_fn(nbrs)
 
-        # 2. Bloom filter: drop already-seen neighbours, insert fresh ones.
-        fresh, filt = bloomlib.bloom_query_and_set(s.filt, nbrs, valid)
+            # 2. Bloom filter: drop already-seen neighbours, insert fresh.
+            fresh, filt = bloomlib.bloom_query_and_set(s.filt, nbrs, valid)
 
         # 3-5. Distances + sort + select + merge: the StepFn boundary
         #    ("reference" XLA / "staged" per-stage kernels / "fused"
         #    megakernel -- one pallas_call, candidates never leave VMEM).
         #    The prefetched path additionally issues hop k+1's expected
         #    gather inside the step (§4.6 seam) and returns its ticket.
-        if prefetch_fn is None:
-            wl, u_next, active = step_fn.step(s.wl, nbrs, fresh, s.active)
-            tok = s.tok
-        else:
-            wl, u_next, active, tok = step_fn.step_with_prefetch(
-                s.wl, nbrs, fresh, s.active, prefetch_fn
-            )
+        with jax.named_scope("bang.step"):
+            if prefetch_fn is None:
+                wl, u_next, active = step_fn.step(s.wl, nbrs, fresh, s.active)
+                tok = s.tok
+            else:
+                wl, u_next, active, tok = step_fn.step_with_prefetch(
+                    s.wl, nbrs, fresh, s.active, prefetch_fn
+                )
 
         # 6. Record the expansion for re-ranking (paper: every candidate sent
         #    to the CPU is retained for the final re-rank).
-        b_idx = jnp.arange(B, dtype=jnp.int32)
-        pos = jnp.minimum(s.hist_len, C - 1)
-        hist = s.hist_ids.at[b_idx, pos].set(
-            jnp.where(active, u_next, s.hist_ids[b_idx, pos])
-        )
-        hist_len = s.hist_len + active.astype(jnp.int32)
+        with jax.named_scope("bang.history"):
+            b_idx = jnp.arange(B, dtype=jnp.int32)
+            pos = jnp.minimum(s.hist_len, C - 1)
+            hist = s.hist_ids.at[b_idx, pos].set(
+                jnp.where(active, u_next, s.hist_ids[b_idx, pos])
+            )
+            hist_len = s.hist_len + active.astype(jnp.int32)
 
         return _State(wl, filt, hist, hist_len, u_next, active, s.it + 1, tok)
 

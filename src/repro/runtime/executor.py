@@ -42,6 +42,9 @@ the pipeline stays resident on the GPU across query batches):
   * **Async dispatch.** `dispatch()` returns a `SearchHandle` without
     blocking; `finish()` blocks on *both* ids and dists and reports
     steady-state wall time separated from compile time (`SearchStats`).
+  * **Stage map.** `stage_map()` names the stage (`core.search.STAGES`) of
+    every op of the compiled executables, so a device trace's op times can
+    be summed per stage under names that survive a change to the program.
 
 Typical use::
 
@@ -54,6 +57,7 @@ from __future__ import annotations
 import dataclasses
 import time
 import warnings
+import weakref
 from typing import Any
 
 import jax
@@ -68,6 +72,8 @@ from repro.core.search import SearchConfig
 from repro.core.vamana import VamanaGraph
 
 from .hostio import HostIOConfig, HostIORuntime
+from .telemetry.stages import op_stages
+from .telemetry.tracing import NO_SPAN
 
 Array = jax.Array
 
@@ -237,6 +243,38 @@ class SearchExecutor:
         rt = self.hostio_runtime
         return None if rt is None else rt.service
 
+    def stage_map(self) -> dict[str, str]:
+        """HLO instruction name -> stage, over the executables compiled so far.
+
+        Read from the compiled text (`telemetry.stages.op_stages`). A
+        device trace names an op by its instruction name alone, so a name
+        that the executables give different stages, or a stage in one and
+        none in another, is left out.
+        """
+        seen: dict[str, set] = {}
+        for compiled in self._cache.values():
+            text = compiled.as_text()
+            for name, stage in op_stages(text, searchlib.STAGES).items():
+                seen.setdefault(name, set()).add(stage)
+        return {name: next(iter(st)) for name, st in seen.items()
+                if len(st) == 1 and None not in st}
+
+    def _tracer_fn(self):
+        """A function that returns the attached telemetry tracer or None.
+
+        Host callbacks call it at call time, so attaching one never changes
+        a traced program. It holds the executor weakly: a compiled program
+        that held it strongly would keep it alive for good.
+        """
+        ref = weakref.ref(self)
+
+        def tracer():
+            ex = ref()
+            tel = None if ex is None else ex.telemetry
+            return None if tel is None else tel.tracer
+
+        return tracer
+
     def set_telemetry(self, telemetry) -> "SearchExecutor":
         """Attach (or detach, with None) a telemetry bundle.
 
@@ -307,8 +345,12 @@ class SearchExecutor:
         entry = self._cache.get(key)
         if entry is not None:
             return entry, 0.0
+        tel = self.telemetry
+        tr = None if tel is None else tel.tracer
         t0 = time.perf_counter()
-        with warnings.catch_warnings():
+        with NO_SPAN if tr is None else tr.span(
+                "compile", track="serve", bucket=bucket, k=k,
+                kernel_mode=cfg.kernel_mode), warnings.catch_warnings():
             # Donation is best-effort: when no output aliases the (bucket, d)
             # query buffer (small k), XLA reports it unusable. Expected.
             warnings.filterwarnings(
@@ -318,18 +360,11 @@ class SearchExecutor:
         compile_s = time.perf_counter() - t0
         self.compile_s_total += compile_s
         self._cache[key] = compiled
-        tel = self.telemetry
         if tel is not None:
             tel.registry.counter(
                 "bang_serve_compile_seconds_total",
                 "wall seconds spent compiling search executables",
             ).inc(compile_s)
-            if tel.tracer is not None:
-                tr = tel.tracer
-                t1 = time.perf_counter()
-                tr.complete("compile", tr.at_us(t1 - compile_s), tr.at_us(t1),
-                            track="serve", bucket=bucket, k=k,
-                            kernel_mode=cfg.kernel_mode)
         return compiled, compile_s
 
     def _compile(self, key, bucket: int, d: int, k: int, rerank: bool,
@@ -355,7 +390,9 @@ class SearchExecutor:
                 ids = res.worklist.ids[:, :k]
                 dists = res.worklist.dists[:, :k]
             else:
-                table = pqlib.build_dist_table(pqlib.PQCodec(codebooks), queries)
+                with jax.named_scope("bang.table"):
+                    table = pqlib.build_dist_table(
+                        pqlib.PQCodec(codebooks), queries)
                 if variant == "inmem":
                     res = searchlib.search_inmem(
                         queries, table, codes, adjacency,
@@ -375,6 +412,7 @@ class SearchExecutor:
                             queries, res.history_ids, k,
                             data_np=self._data_np,
                             use_kernels=cfg.uses_kernels(),
+                            tracer=self._tracer_fn(),
                         )
                     else:
                         ids, dists = rr.rerank(
@@ -553,21 +591,14 @@ class SearchExecutor:
         t0 = time.perf_counter()
         tel = self.telemetry
         if tel is not None and tel.profiler is not None:
-            # Stamp kernel metadata for codes-stream accounting and bracket
-            # the dispatch with a jax.profiler annotation so device
-            # timelines carry the same names as our Chrome trace. Host-side
+            # Stamp kernel metadata for codes-stream accounting. Host-side
             # only: the compiled program is the same object either way.
             R, m, n_block = self.autotune_shape()
             tel.profiler.set_kernel_info(
                 kernel_mode=cfg.kernel_mode, batch=bucket, n=n_block, m=m,
                 R=R, tile_rows=cfg.codes_tile_rows,
             )
-            with tel.profiler.annotate(
-                    f"bang_dispatch:{cfg.kernel_mode}:b{bucket}"):
-                ids, dists, n_hops, n_iters = self._run(
-                    compiled, q_dev, tomb_dev)
-        else:
-            ids, dists, n_hops, n_iters = self._run(compiled, q_dev, tomb_dev)
+        ids, dists, n_hops, n_iters = self._run(compiled, q_dev, tomb_dev)
         return SearchHandle(
             ids=ids, dists=dists, n_hops=n_hops, n_iters=n_iters,
             batch=B, bucket=bucket, dispatch_t=t0, compile_s=compile_s,

@@ -76,6 +76,7 @@ from repro.runtime.resilience import (
     TransientGatherError,
     backoff_delay,
 )
+from repro.runtime.telemetry.tracing import NO_SPAN
 
 __all__ = ["NeighborService"]
 
@@ -661,22 +662,25 @@ class NeighborService:
     # (without a ServePipeline owning the lifecycle), and an explicit
     # start() merely warms the threads up front. stop() remains the
     # tear-down; a stopped service revives itself if traffic returns.
+    def _gather_span(self, shard: int, own: np.ndarray, **args):
+        """The `gather` span of one callback, or NO_SPAN when not tracing."""
+        tel = self._tel
+        if tel is None or tel.tracer is None:
+            return NO_SPAN
+        return tel.tracer.span("gather", track=f"hostio-p{shard}", **args,
+                               rows=int(own.sum()))
+
     def request(self, shard, rel, own, cache_hit) -> np.ndarray:
         """Synchronous path: block on the pooled gather (no prefetch)."""
         self._ensure_started()
-        t0 = time.perf_counter()
         shard = int(np.asarray(shard))
         own = np.asarray(own, bool)
-        out = self._gather(shard, rel, own)
-        t1 = time.perf_counter()
+        with self._gather_span(shard, own, mode="sync"):
+            t0 = time.perf_counter()
+            out = self._gather(shard, rel, own)
+            t1 = time.perf_counter()
         self._account(shard, own, np.asarray(cache_hit, bool), t1 - t0)
         self._bump(requests=1, latency_s_total=t1 - t0)
-        tel = self._tel
-        if tel is not None and tel.tracer is not None:
-            tr = tel.tracer
-            tr.complete("gather", tr.at_us(t0), tr.at_us(t1),
-                        track=f"hostio-p{shard}", mode="sync",
-                        rows=int(own.sum()))
         return out
 
     def issue(self, shard, rel, own) -> np.ndarray:
@@ -728,11 +732,21 @@ class NeighborService:
         hedged gather as well) -- collect never blocks past its wait
         budget, which is what bounds the request deadline end to end.
         """
-        t0 = time.perf_counter()
         shard = int(np.asarray(shard))
         rel = np.asarray(rel)
         own = np.asarray(own, bool)
         seq = int(np.asarray(seq).ravel()[0])
+        with self._gather_span(shard, own, mode="collect", seq=seq):
+            t0 = time.perf_counter()
+            out = self._redeem(shard, rel, own, seq, t0)
+            t1 = time.perf_counter()
+        self._account(shard, own, np.asarray(cache_hit, bool), t1 - t0)
+        self._bump(requests=1, latency_s_total=t1 - t0)
+        return out
+
+    def _redeem(self, shard: int, rel: np.ndarray, own: np.ndarray,
+                seq: int, t0: float) -> np.ndarray:
+        """collect()'s work: the ticket's rows, patched or re-gathered."""
         with self._lock:
             p = self._pending.pop(seq, None)
         if p is not None and not p.done.wait(timeout=self._wait_budget_s()):
@@ -769,14 +783,6 @@ class NeighborService:
                 # Issued-but-unwanted lanes must contribute 0 again.
                 out = np.where((own | reuse)[:, None], out, 0).astype(np.int32)
                 self._bump(prefetch_lane_mismatches=int(redo.sum()))
-        t1 = time.perf_counter()
-        self._account(shard, own, np.asarray(cache_hit, bool), t1 - t0)
-        self._bump(requests=1, latency_s_total=t1 - t0)
-        if tel is not None and tel.tracer is not None:
-            tr = tel.tracer
-            tr.complete("gather", tr.at_us(t0), tr.at_us(t1),
-                        track=f"hostio-p{shard}", mode="collect", seq=seq,
-                        rows=int(own.sum()))
         return out
 
     def _account(self, shard: int, own: np.ndarray, cache_hit: np.ndarray,

@@ -48,7 +48,8 @@ work of the next query batch with the device-side search of the current one.
     request id whose lifecycle lands on the Chrome trace timeline as
     exactly one `request` span (outcome served/cache_hit) or
     `request_shed`/`request_expired` instant, micro-batches emit
-    `admission`/`dispatch`/`device`/`compile` spans, and
+    `admission`/`dispatch`/`device`/`compile` spans, each drain a `drain`
+    span and a `gc` span per Python collection inside it, and
     `ServeStats.telemetry` carries the registry delta over the drain
     window. Detached (the default) the pipeline behaves identically --
     telemetry never touches compile caches or traced programs.
@@ -80,6 +81,7 @@ from repro.core.bang import recall_at_k
 from repro.core.search import SearchConfig
 
 from .executor import SearchExecutor, SearchHandle
+from .telemetry.tracing import NO_SPAN
 
 
 @dataclasses.dataclass
@@ -353,6 +355,16 @@ class ServePipeline:
         self, on_batch: Callable[[BatchReport], None] | None = None
     ) -> tuple[np.ndarray, np.ndarray, ServeStats]:
         """Process every queued query; results aligned to submission order."""
+        tr = None if self._tel is None else self._tel.tracer
+        if tr is None:
+            return self._drain(on_batch)
+        with tr.span("drain", track="serve", rows=len(self._queue)), \
+                tr.gc_spans():
+            return self._drain(on_batch)
+
+    def _drain(
+        self, on_batch: Callable[[BatchReport], None] | None
+    ) -> tuple[np.ndarray, np.ndarray, ServeStats]:
         n = len(self._queue)
         k = self._k
         # Mutation-epoch fence: every insert()/delete()/consolidate() on a
@@ -445,22 +457,22 @@ class ServePipeline:
                     rows = [p[1] for p in popped]
                     queries = np.stack([r[0] for r in rows])
                     t_disp = time.perf_counter()
-                    try:
-                        handle = self._ex.dispatch(
-                            queries, k, cfg=self._cfg, rerank=self._rerank
-                        )
-                    except BaseException:
-                        # The popped rows never reached the device; put them
-                        # back so the outer handler re-enqueues them.
-                        misses.extendleft(reversed(popped))
-                        raise
-                    if tr is not None:
-                        # Host-side dispatch work (bucketing, padding,
-                        # upload, async launch); device compute shows up as
-                        # the following `device` span.
-                        tr.complete("dispatch", tr.at_us(t_disp), tr.now_us(),
-                                    track="serve", size=len(rows),
-                                    bucket=handle.bucket)
+                    # Host-side dispatch work (bucketing, padding, upload,
+                    # async launch); device compute shows up as the
+                    # following `device` span.
+                    with NO_SPAN if tr is None else tr.span(
+                            "dispatch", track="serve", size=len(rows)) as sp:
+                        try:
+                            handle = self._ex.dispatch(
+                                queries, k, cfg=self._cfg, rerank=self._rerank
+                            )
+                        except BaseException:
+                            # The popped rows never reached the device; put
+                            # them back so the outer handler re-enqueues them.
+                            misses.extendleft(reversed(popped))
+                            raise
+                        if tr is not None:
+                            sp.set(bucket=handle.bucket)
                     nxt = (rows, at_idx, handle, t_disp)
 
                 if inflight is not None:

@@ -34,12 +34,14 @@ components:
 
   tracer     optional -- per-request spans and hostio/mutation/resilience
              timeline events, exported as Chrome `trace_event` JSON
-             (span vocabulary in `tracing.py`).
+             (span vocabulary in `tracing.py`); spans that wrap work
+             also enter `jax.profiler.TraceAnnotation`s named `bang.<span>`,
+             so a profiler trace shows them on the device's clock.
   recorder   optional -- `FlightRecorder` ring buffer; the resilience
              layer triggers a structured postmortem dump on failover /
              partition-down / degrade / deadline-expiry / shed.
-  profiler   optional -- `HopProfiler` per-hop host-seam profiling +
-             `jax.profiler` annotations (see `profile.py`).
+  profiler   optional -- `HopProfiler` per-hop host-seam profiling (see
+             `profile.py`).
 
 Design contract (test-enforced): telemetry NEVER enters an executor's
 compile-cache key and never changes a traced program -- with the bundle

@@ -18,11 +18,9 @@ and, from kernel metadata the executor stamps at dispatch time
 summary reports measured per-hop wall next to the modeled HBM traffic --
 the same pairing `bench_kernels.py` prints for the beyond-VMEM lane.
 
-`annotate(name)` additionally brackets a region with
-`jax.profiler.TraceAnnotation` when the profiler is active and jax
-exposes it, so device timelines captured with `jax.profiler.trace` carry
-the same hop names as our own Chrome trace. When inactive (or on jax
-builds without the API) it is a no-op context.
+Device timelines get their names elsewhere: the tracer's spans enter
+`jax.profiler.TraceAnnotation`s (`tracing.py`), and the search's stages
+carry named scopes (`core.search.STAGES`).
 
 Crucially none of this perturbs compilation: the profiler attaches as
 executor *state* (`set_telemetry`), never enters the compile-cache key,
@@ -32,7 +30,6 @@ XLA treats as opaque. `tests/test_telemetry.py` pins that.
 """
 from __future__ import annotations
 
-import contextlib
 import threading
 
 __all__ = ["HopProfiler"]
@@ -73,23 +70,6 @@ class HopProfiler:
                 "n": int(n), "m": int(m), "R": int(R),
                 "tile_rows": int(tile_rows),
             }
-
-    # ----------------------------------------------------------- annotations
-    @contextlib.contextmanager
-    def annotate(self, name: str):
-        """Bracket a region with jax.profiler.TraceAnnotation if available."""
-        ann = None
-        try:
-            import jax.profiler as _jp
-
-            ann = _jp.TraceAnnotation(name)
-        except Exception:
-            ann = None
-        if ann is None:
-            yield
-        else:
-            with ann:
-                yield
 
     # -------------------------------------------------------------- summary
     @property

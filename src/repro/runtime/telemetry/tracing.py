@@ -23,11 +23,17 @@ Span vocabulary (the names tests and docs pin):
                            bucket, compile_s
     ``compile``            executor cache miss (args: bucket, k,
                            kernel_mode)
+    ``drain``              one ServePipeline.drain() call; args: rows
+    ``gc``                 a Python garbage collection during a drain;
+                           args: generation, collected
   hostio (track "hostio-p<shard>"):
     ``gather``             one blocking callback gather (mode
                            "sync" | "collect"); args: rows, seq
     ``prefetch_gather``    background ticket gather, issue -> done; args:
                            seq, hidden_s (the overlapped share)
+  re-rank (track "rerank"):
+    ``rerank_gather``      one host callback of the Base re-rank's
+                           candidate-vector gather; args: rows
   mutation (track "mutation"):
     ``consolidate``        background consolidation; args: generation
   resilience instants (track "events"):
@@ -40,6 +46,12 @@ the disabled path costs one attribute test (zero hot-path cost when off).
 Timestamps are `time.perf_counter()` microseconds relative to the
 tracer's birth, the monotonic clock the serve pipeline already uses.
 
+A span used as a context manager (`with tracer.span(...)`) wraps work on
+the calling thread, and also enters a `jax.profiler.TraceAnnotation` named
+``bang.<name>`` for as long: while a profiler trace runs, the span lands on
+its host plane, on the same clock as the device's ops. Call sites with no
+tracer attached enter the shared `NO_SPAN` instead, which records nothing.
+
 `to_chrome()` emits the Chrome trace-event JSON object format
 (`{"traceEvents": [...]}`): complete events `ph:"X"` with `ts`/`dur` in
 microseconds, instants `ph:"i"`, plus `ph:"M"` thread_name metadata so
@@ -48,17 +60,36 @@ runs against a generated file.
 """
 from __future__ import annotations
 
+import contextlib
+import gc
 import json
 import threading
 import time
 
-__all__ = ["Span", "Tracer", "validate_chrome_trace"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["NO_SPAN", "Span", "Tracer", "validate_chrome_trace"]
+
+
+class _NoSpan:
+    """What a call site enters while no tracer is attached."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+
+NO_SPAN = _NoSpan()
 
 
 class Span:
     """An open interval; `end()` (or the context manager) emits it once."""
 
-    __slots__ = ("_tracer", "name", "track", "args", "_t0", "_done")
+    __slots__ = ("_tracer", "name", "track", "args", "_t0", "_done", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, track: str,
                  args: dict) -> None:
@@ -68,21 +99,36 @@ class Span:
         self.args = args
         self._t0 = tracer._now_us()
         self._done = False
+        self._ann = None
+
+    def set(self, **args) -> None:
+        """Add args known only once the work is under way."""
+        self.args.update(args)
 
     def end(self, **extra_args) -> None:
+        self._close(self._tracer._now_us(), extra_args)
+
+    def _close(self, t1_us: float, extra_args: dict) -> None:
         if self._done:
             return
         self._done = True
         if extra_args:
             self.args.update(extra_args)
-        self._tracer._emit_complete(self.name, self.track, self._t0,
-                                    self._tracer._now_us(), self.args)
+        self._tracer._emit_complete(self.name, self.track, self._t0, t1_us,
+                                    self.args)
 
+    # The annotation brackets the span's own stamps, with no work between
+    # them, so the two agree on the profiler's clock.
     def __enter__(self) -> "Span":
+        self._ann = TraceAnnotation(f"bang.{self.name}")
+        self._ann.__enter__()
+        self._t0 = self._tracer._now_us()
         return self
 
     def __exit__(self, *exc) -> None:
-        self.end()
+        t1 = self._tracer._now_us()
+        self._ann.__exit__(*exc)
+        self._close(t1, {})
 
 
 class Tracer:
@@ -95,7 +141,9 @@ class Tracer:
     """
 
     def __init__(self, max_events: int = 200_000) -> None:
-        self._lock = threading.Lock()
+        # Reentrant: a collection can start while this thread holds the
+        # lock, and `gc_spans` then emits from inside the collection.
+        self._lock = threading.RLock()
         self._events: list[dict] = []
         self._tids: dict[str, int] = {}
         self._birth = time.perf_counter()
@@ -159,6 +207,30 @@ class Tracer:
                  track: str = "serve", **args) -> None:
         """Emit a complete event from caller-measured timestamps."""
         self._emit_complete(name, track, t0_us, t1_us, dict(args))
+
+    @contextlib.contextmanager
+    def gc_spans(self, track: str = "serve"):
+        """Record a `gc` span for each Python collection inside the block."""
+        open_spans: list[Span] = []
+        with self._lock:
+            self._tid_locked(track)   # no new track mid-emission
+
+        def on_gc(phase: str, info: dict) -> None:
+            # Collections do not nest and run on the thread that set them
+            # off, so the one open span is this collection's.
+            if phase == "start":
+                sp = self.span("gc", track, generation=info["generation"])
+                open_spans.append(sp.__enter__())
+            elif open_spans:
+                sp = open_spans.pop()
+                sp.set(collected=info["collected"])
+                sp.__exit__(None, None, None)
+
+        gc.callbacks.append(on_gc)
+        try:
+            yield
+        finally:
+            gc.callbacks.remove(on_gc)
 
     def instant(self, name: str, track: str = "events", **args) -> None:
         with self._lock:

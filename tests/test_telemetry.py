@@ -262,9 +262,6 @@ def test_hop_profiler_summary_and_bounds():
     assert per_hop is not None and per_hop >= 0
     assert s["codes_stream_bytes_total"] == per_hop * s["hops"]
 
-    with prof.annotate("bang_test_region"):   # no-op context must not raise
-        pass
-
 
 # ========================================================= telemetry bundle
 def test_telemetry_create_flags():
@@ -534,3 +531,225 @@ def test_mutation_telemetry(small_ann_index):
         assert np.asarray(ids).shape == (2, K)
         assert reg.counter(
             "bang_serve_compile_seconds_total").value > before
+
+
+# ============================================== stage scopes and host spans
+BASE_HIO = HostIOConfig()          # the Base deployment's host-I/O service
+
+
+@pytest.mark.parametrize("mode", ["reference", "staged"])
+@pytest.mark.parametrize("prefetch", [False, True])
+def test_stage_map_holds_every_stage(small_ann_index, mode, prefetch):
+    """Every stage scope reaches the compiled Base program's op metadata; the
+    host callbacks belong to the exchange or the re-rank, the prefetch ones
+    too though they are issued from inside the step."""
+    from repro.core.search import STAGES
+
+    data, idx = small_ann_index
+    ex = SearchExecutor.from_index(
+        idx, variant="base", hostio=HostIOConfig(prefetch=prefetch))
+    assert ex.stage_map() == {}                  # nothing compiled yet
+    ex.search(np.asarray(data[:4]), K, cfg=SearchConfig(t=16,
+                                                        kernel_mode=mode))
+    stages = ex.stage_map()
+    assert set(stages.values()) == set(STAGES)
+    callbacks = {stages.get(n) for n in stages if n.startswith("pure_callback")}
+    assert callbacks == {"bang.fetch", "bang.rerank"}
+
+
+def test_op_stages_from_metadata_fused_computations_and_neighbours():
+    """An op's own innermost scope; else its fused computation's; else the
+    one stage of its users or operands; else its caller's. The search's own
+    loop keeps none."""
+    from repro.core.search import STAGES
+    from repro.runtime.telemetry.stages import op_stages
+
+    text = """HloModule jit_pipeline
+%fused.1 (p.1: f32[2]) -> f32[2] {
+  %p.1 = f32[2]{0:T(128)} parameter(0)
+  ROOT %m.1 = f32[2]{0:T(128)} multiply(%p.1, %p.1), metadata={op_name="jit(p)/while/body/bang.bloom/mul"}
+}
+%relayout.body (q.1: f32[2]) -> f32[2] {
+  %q.1 = f32[2]{0:T(128)} parameter(0)
+  ROOT %r.1 = f32[2]{0:T(128)} negate(%q.1)
+}
+%loop.body (q.2: f32[2]) -> f32[2] {
+  %q.2 = f32[2]{0:T(128)} parameter(0)
+  ROOT %l.1 = f32[2]{0:T(128)} negate(%q.2)
+}
+ENTRY %main (x: f32[2]) -> f32[2] {
+  %x = f32[2]{0:T(128)} parameter(0)
+  %a.1 = f32[2]{0:T(1024)(128)} add(%x, %x), metadata={op_name="jit(p)/bang.table/add"}
+  %b.2 = f32[2]{0} custom-call(%a.1), metadata={op_name="jit(p)/while/body/bang.step/bang.fetch/pure_callback"}
+  %c.3 = f32[2]{0:T(1024)(128)} copy(%b.2)
+  %w.4 = f32[2]{0} while(%c.3), condition=%relayout.body, body=%relayout.body
+  %f.5 = f32[2]{0} fusion(%w.4), kind=kCustom, calls=%fused.1
+  %mix = (f32[2]{0}, f32[2]{0}) tuple(%a.1, %b.2)
+  ROOT %s.6 = (f32[2]{0}, f32[2]{0}) while(%mix), body=%loop.body, metadata={op_name="jit(p)/while"}
+}
+"""
+    got = op_stages(text, STAGES)
+    assert got["a.1"] == "bang.table"
+    assert got["b.2"] == "bang.fetch"         # innermost of step/fetch
+    assert got["f.5"] == "bang.bloom"         # its fused computation's
+    assert got["w.4"] == "bang.bloom"         # its one user's
+    assert got["c.3"] == "bang.bloom"         # its one user's, through w.4
+    assert got["r.1"] == "bang.bloom"         # its loop's, w.4
+    assert got["s.6"] is None                 # the search loop: mixed input
+    assert got["l.1"] is None                 # and its body keeps none
+
+
+def test_stage_map_leaves_out_names_the_executables_disagree_on():
+    text = """HloModule jit_pipeline
+ENTRY %main (x: f32[2]) -> f32[2] {
+  %a.1 = f32[2]{0} add(%x, %x), metadata={op_name="jit(p)/bang.table/add"}
+  ROOT %b.2 = f32[2]{0} custom-call(%a.1), metadata={op_name="jit(p)/bang.fetch/pure_callback"}
+}
+"""
+
+    class Fake:
+        def __init__(self, text):
+            self.text = text
+
+        def as_text(self):
+            return self.text
+
+    ex = SearchExecutor.__new__(SearchExecutor)
+    ex._cache = {1: Fake(text), 2: Fake(text.replace("bang.table", "x"))}
+    # a.1 is bang.table in one executable and bang.fetch's input in the other
+    assert ex.stage_map() == {"b.2": "bang.fetch"}
+
+
+def _host_plane_events(trace_dir) -> list[tuple[str, int, int]]:
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                out.extend((e.name, int(e.start_ns), int(e.end_ns))
+                           for e in line.events if e.name.startswith("bang."))
+    return out
+
+
+def test_spans_land_on_the_profiler_host_plane(small_ann_index, tmp_path):
+    """While a profiler trace runs, each span that wraps work is also a
+    `bang.<name>` event on its host plane, on the device's clock, agreeing
+    with the Chrome-JSON span; the re-rank records one span per callback."""
+    import jax
+
+    from repro.core.rerank import gather_chunk_rows
+
+    data, idx = small_ann_index
+    cfg = SearchConfig(t=16)
+    bucket = 64
+    q = np.asarray(data[:2 * bucket] + 0.01, np.float32)
+    tel = Telemetry.create(trace=True)
+    ex = SearchExecutor.from_index(idx, variant="base", hostio=BASE_HIO)
+    with ServePipeline(ex, k=K, cfg=cfg, max_batch=bucket,
+                       telemetry=tel) as pipe:
+        pipe.submit(q[:bucket])
+        pipe.drain()                        # compile outside the trace
+        t_trace = tel.tracer.now_us()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            pipe.submit(q)
+            pipe.drain()
+        finally:
+            jax.profiler.stop_trace()
+
+    spans = {}
+    for e in tel.tracer.events():
+        if e["ph"] == "X" and e["ts"] >= t_trace:
+            spans.setdefault(e["name"], []).append(e)
+    host = {}
+    for n, s, e in _host_plane_events(tmp_path):
+        host.setdefault(n[len("bang."):], []).append((s, e))
+    names = ("gather", "dispatch", "drain", "rerank_gather")
+    assert set(names) <= set(host)
+    (drain,) = spans["drain"]
+    (drain_host,) = host["drain"]
+    for name in names:
+        ours = sorted(spans[name], key=lambda e: e["ts"])
+        theirs = sorted(host[name])
+        assert len(ours) == len(theirs), name
+        for e, (s, t) in zip(ours, theirs):
+            # durations, and starts from the drain's, within 0.1 ms
+            assert e["dur"] == pytest.approx((t - s) / 1e3, abs=100), name
+            assert e["ts"] - drain["ts"] == pytest.approx(
+                (s - drain_host[0]) / 1e3, abs=100), name
+
+    # one rerank_gather span per callback: the chunks of (bucket, C) ids
+    rows = bucket * cfg.iters()
+    chunks = -(-rows // gather_chunk_rows(data.shape[1]))
+    assert chunks > 1
+    assert len(spans["rerank_gather"]) == 2 * chunks
+    assert sum(e["args"]["rows"] for e in spans["rerank_gather"]) == 2 * rows
+
+
+def test_gc_spans_inside_a_traced_drain(small_ann_index):
+    import gc
+
+    data, idx = small_ann_index
+    tel = Telemetry.create(trace=True)
+    ex = SearchExecutor.from_index(idx, variant="inmem")
+    n_callbacks = len(gc.callbacks)
+
+    def collect(_report):
+        assert len(gc.callbacks) == n_callbacks + 1
+        gc.collect()
+
+    with ServePipeline(ex, k=K, cfg=CFG, max_batch=8, telemetry=tel) as pipe:
+        pipe.submit(np.asarray(data[:8]))
+        pipe.drain(on_batch=collect)
+    assert len(gc.callbacks) == n_callbacks
+    (drain,) = [e for e in tel.tracer.events() if e["name"] == "drain"]
+    gcs = [e for e in tel.tracer.events() if e["name"] == "gc"]
+    assert any(e["args"]["generation"] == 2 for e in gcs)
+    for e in gcs:
+        assert e["args"]["collected"] >= 0
+        assert drain["ts"] <= e["ts"] <= e["ts"] + e["dur"] <= \
+            drain["ts"] + drain["dur"]
+
+
+@pytest.mark.parametrize("tel", [None, "registry_only"])
+def test_no_tracer_creates_no_span_annotation_or_gc_hook(
+        small_ann_index, monkeypatch, tel):
+    from repro.runtime.telemetry import tracing
+
+    def forbidden(*a, **kw):
+        raise AssertionError("created with no tracer attached")
+
+    monkeypatch.setattr(tracing, "TraceAnnotation", forbidden)
+    monkeypatch.setattr(tracing.Span, "__init__", forbidden)
+    import gc
+
+    n_callbacks = len(gc.callbacks)
+    data, idx = small_ann_index
+    telemetry = None if tel is None else Telemetry.create()
+    ex = SearchExecutor.from_index(idx, variant="base", hostio=BASE_HIO)
+    with ServePipeline(ex, k=K, cfg=CFG, max_batch=8,
+                       telemetry=telemetry) as pipe:
+        pipe.submit(np.asarray(data[:16]))
+        pipe.drain(on_batch=lambda _r: gc.collect())
+        assert len(gc.callbacks) == n_callbacks
+
+
+def test_compiled_base_program_does_not_keep_its_executor(small_ann_index):
+    """The re-rank callbacks read the executor's telemetry at call time, yet
+    a dropped executor (its host data with it) is still freed."""
+    import gc
+    import weakref
+
+    data, idx = small_ann_index
+    ex = SearchExecutor.from_index(idx, variant="base", hostio=BASE_HIO)
+    ex.set_telemetry(Telemetry.create(trace=True))
+    ex.search(np.asarray(data[:8]), K, cfg=CFG)
+    ref = weakref.ref(ex)
+    del ex
+    gc.collect()
+    assert ref() is None
