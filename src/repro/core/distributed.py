@@ -154,7 +154,8 @@ def sharded_adc_distance_fn(
     table: (B, m, 256) replicated over `axis`; codes_local: (n_loc, m).
     kernel_mode (falls back to the legacy use_kernels flag):
 
-      "reference"  XLA gather + take_along_axis ADC
+      "reference"  XLA gather + `pq.adc_distance` ADC (one-hot select on
+                   the TPU, take_along_axis elsewhere)
       "staged"     XLA gather into a (B, R, m) HBM temporary + pq_adc kernel
       "fused"      search_step.local_adc -- the fetch happens *inside* the
                    kernel on the shard's packed codes (VMEM-resident while

@@ -148,14 +148,9 @@ def build_dist_table(codec: PQCodec, queries: Array) -> Array:
     return table.transpose(1, 0, 2)
 
 
-@jax.jit
-def adc_distance(table: Array, codes: Array) -> Array:
-    """Asymmetric distance computation (paper §4.5).
-
-    table: (B, m, 256) per-query PQ distance table.
-    codes: (B, R, m) uint8 codes of each query's R candidate points.
-    returns (B, R) approximate squared L2 distances.
-    """
+def adc_gather(table: Array, codes: Array) -> Array:
+    """ADC as one element gather of the table entries (`adc_distance` off
+    the TPU)."""
     idx = codes.astype(jnp.int32)                                   # (B, R, m)
     # take_along_axis over the 256 axis: table (B, m, 256) -> (B, R, m)
     gathered = jnp.take_along_axis(
@@ -169,6 +164,47 @@ def adc_distance(table: Array, codes: Array) -> Array:
     for j in range(1, gathered.shape[-1]):
         acc = acc + gathered[..., j]
     return acc
+
+
+def adc_onehot(table: Array, codes: Array) -> Array:
+    """ADC as a one-hot select of each subspace's table row and a sum over
+    its 256 lanes (`adc_distance` on the TPU).
+
+    The lane sum has one non-zero term, so each lookup is exact, and the
+    subspaces are added in order: bit-identical to `adc_gather` (and to the
+    Pallas kernels' `adc_column`) for any table without -0.0 entries, which
+    a table of squared distances never holds. One subspace at a time, so no
+    (B, R, m, 256) array is ever formed.
+    """
+    idx = codes.astype(jnp.int32)                                   # (B, R, m)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, N_CLUSTERS), 2)
+
+    def lookup(j):                                                  # -> (B, R)
+        hit = idx[:, :, j, None] == lane                            # (B, R, 256)
+        return jnp.sum(jnp.where(hit, table[:, None, j, :], 0.0), axis=-1)
+
+    acc = lookup(0)
+    for j in range(1, idx.shape[-1]):
+        acc = acc + lookup(j)
+    return acc
+
+
+@jax.jit
+def adc_distance(table: Array, codes: Array) -> Array:
+    """Asymmetric distance computation (paper §4.5).
+
+    table: (B, m, 256) per-query PQ distance table.
+    codes: (B, R, m) uint8 codes of each query's R candidate points.
+    returns (B, R) approximate squared L2 distances.
+
+    The formulation follows the platform the call is lowered for: the TPU
+    lowers an element gather to a slow per-element path, so it gets
+    `adc_onehot`; every other backend keeps the `adc_gather` it runs fast.
+    Both give bit-identical distances.
+    """
+    return jax.lax.platform_dependent(
+        table, codes, tpu=adc_onehot, default=adc_gather
+    )
 
 
 def quantization_error(codec: PQCodec, data: Array) -> float:

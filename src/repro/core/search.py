@@ -21,7 +21,9 @@ little work). Each iteration performs exactly the paper's stages:
 The distance/sort/select/merge stages live behind a single pluggable
 **StepFn** boundary (`SearchConfig.kernel_mode`):
 
-    "reference"  pure XLA: take_along_axis ADC + lax.sort (the oracle path)
+    "reference"  pure XLA: `pq.adc_distance` ADC (a one-hot select on the
+                 TPU, which lowers an element gather slowly; take_along_axis
+                 elsewhere) + lax.sort (the oracle path)
     "staged"     separate Pallas kernels per stage (pq_adc / bitonic sort /
                  bitonic merge) -- the (B, R) candidate tile round-trips HBM
                  between every stage

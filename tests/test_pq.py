@@ -1,6 +1,7 @@
 """PQ codec invariants (paper §2.3, §4.2, §4.5)."""
 import numpy as np
 import jax.numpy as jnp
+import pytest
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -27,6 +28,22 @@ def test_adc_equals_decompressed_distance(rng):
         adc = pq.adc_distance(table[b : b + 1], codes[None])[0]
         exact = jnp.sum((dec - q[b]) ** 2, axis=-1)
         np.testing.assert_allclose(np.asarray(adc), np.asarray(exact), rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("b,r,m", [(1, 4, 4), (3, 17, 9), (7, 33, 32), (5, 64, 74)])
+def test_adc_onehot_bit_identical_to_gather(b, r, m):
+    """The TPU's one-hot ADC returns the gather's distances bit for bit."""
+    rng = np.random.default_rng(b * 1000 + r * 10 + m)
+    table = rng.standard_normal((b, m, 256)).astype(np.float32)
+    table[rng.random(table.shape) < 0.05] = 0.0            # zero and negative entries
+    codes = rng.integers(0, 256, (b, r, m)).astype(np.uint8)
+    codes[:, ::3, ::2] = 0                                 # both ends of the 256 lanes
+    codes[:, 1::3, 1::2] = 255
+    table, codes = jnp.asarray(table), jnp.asarray(codes)
+    got = np.asarray(pq.adc_onehot(table, codes))
+    want = np.asarray(pq.adc_gather(table, codes))
+    assert got.shape == (b, r)
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
 
 
 def test_encode_is_argmin(rng):

@@ -4,7 +4,8 @@ Nothing runs: each test lowers and compiles one kernel with interpret=False
 at the widths a SIFT1B-shaped deployment uses (d = 128, R = 64, m = 32,
 batches up to 1024, worklists up to 256) against a `v5e:2x2` topology
 description, so a kernel the chip's compiler refuses fails here, at no chip
-time. The topology is described inside a fixture (never at import): only one
+time. The reference mode's XLA ADC is compiled the same way, to check the
+form the chip gets. The topology is described inside a fixture (never at import): only one
 process may load the TPU compiler library, and every test worker imports
 this file.
 """
@@ -35,7 +36,7 @@ def one_chip(topo):
 
 
 @pytest.fixture(scope="module")
-def compile_tpu(one_chip):
+def no_cache():
     # A compile for a described chip is written to the persistent cache but
     # cannot be read back without one: keep the cache out of these tests.
     from jax.experimental.compilation_cache import compilation_cache
@@ -43,15 +44,19 @@ def compile_tpu(one_chip):
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
 
+
+@pytest.fixture(scope="module")
+def compile_tpu(one_chip, no_cache):
     def compile_(fn, *shapes):
         args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
         text = jax.jit(fn).lower(*args).compile().as_text()
         assert "tpu_custom_call" in text
         return text
 
-    yield compile_
-    jax.config.update("jax_enable_compilation_cache", prev)
+    return compile_
 
 
 def _step_shapes(n_lines):
@@ -129,3 +134,15 @@ def test_exact_sq_dists_compiles(compile_tpu):
 
     fn = lambda *a: exact_sq_dists_pallas(*a, interpret=False)
     compile_tpu(fn, ((B, D), jnp.float32), ((B, 392, D), jnp.float32))
+
+
+def test_reference_adc_compiles_without_gather(one_chip, no_cache):
+    """The chip gets the one-hot ADC: no element gather, no (B, R, m, 256)
+    temporary."""
+    from repro.core import pq
+
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in [((B, M, 256), jnp.float32), ((B, R, M), jnp.uint8)]]
+    compiled = pq.adc_distance.lower(*args).compile()
+    assert "gather(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
