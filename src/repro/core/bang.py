@@ -178,6 +178,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import pq as pqlib
+from . import rows as rowslib
 from .search import SearchConfig
 from .vamana import VamanaGraph, build_vamana
 
@@ -204,7 +205,7 @@ class BangIndex:
     codes: Array                 # (n, m) uint8, device-resident (the 74 GB star)
     graph: VamanaGraph           # host adjacency (base) / copied to device (inmem)
     data_np: np.ndarray          # host full vectors (base re-rank source)
-    data_dev: Array | None = None  # device full vectors (inmem/exact variants)
+    data_dev: rowslib.PackedRows | None = None  # device full vectors (inmem/exact)
     _executors: dict[str, Any] = dataclasses.field(
         default_factory=dict, repr=False, compare=False,
     )
@@ -225,9 +226,14 @@ class BangIndex:
         graph: VamanaGraph | None = None,
     ) -> "BangIndex":
         data = np.asarray(data, np.float32)
-        data_dev = jnp.asarray(data)          # one upload serves all three uses
+        data_dev = jnp.asarray(data)          # PQ training and encoding
         codec = pqlib.train_pq(data_dev, m, iters=kmeans_iters)
         codes = pqlib.pq_encode(codec, data_dev)
+        if keep_device_data:
+            # The searches' copy goes up packed (`core.rows`), once the
+            # encoder's copy is done with, so the two never share the device.
+            codes.block_until_ready()
+            del data_dev
         if graph is None:
             graph = build_vamana(data, R=R, L=L_build, alpha=alpha, seed=seed)
         return cls(
@@ -235,7 +241,7 @@ class BangIndex:
             codes=codes,
             graph=graph,
             data_np=data,
-            data_dev=data_dev if keep_device_data else None,
+            data_dev=rowslib.pack(data) if keep_device_data else None,
         )
 
     @property

@@ -11,6 +11,11 @@ We implement FNV-1a over the 4 little-endian bytes of the node id in uint32
 arithmetic, exactly as the reference C implementation would, and derive the two
 probe positions Kirsch-Mitzenmacher style from two independently-seeded FNV-1a
 passes.
+
+The batch's filters live in one flat (batch * z,) array, filter b in
+[b * z, (b + 1) * z). That is the shape a TPU scatters into in place: a
+(batch, z) array was laid out column-major, so every insertion relaid all
+batch * z bytes out to flat and back (409 MB twice a hop at batch 1024).
 """
 from __future__ import annotations
 
@@ -45,36 +50,40 @@ def bloom_hashes(ids: Array, z: int) -> tuple[Array, Array]:
 
 
 def bloom_init(batch: int, z: int) -> Array:
-    """(batch, z) uint8 filter, all clear. The paper's 'array of z bools'."""
-    return jnp.zeros((batch, z), jnp.uint8)
+    """`batch` filters of z uint8 each, all clear: the paper's 'array of z
+    bools' per query, flat (see the module docstring)."""
+    if batch * z >= 2**31:
+        raise ValueError(f"batch {batch} x z {z} bloom bytes exceed int32 "
+                         "positions")
+    return jnp.zeros((batch * z,), jnp.uint8)
+
+
+def _positions(filt: Array, ids: Array) -> tuple[Array, Array]:
+    """Flat positions of the two probes of ids (B, R) in B filters."""
+    B = ids.shape[0]
+    z = filt.shape[0] // B
+    p1, p2 = bloom_hashes(ids, z)
+    row = (jnp.arange(B, dtype=jnp.int32) * z)[:, None]
+    return row + p1, row + p2
 
 
 def bloom_set(filt: Array, ids: Array, valid: Array | None = None) -> Array:
-    """Insert ids (B, R) into per-query filters (B, z). valid masks padding."""
-    z = filt.shape[-1]
-    p1, p2 = bloom_hashes(ids, z)
-    one = jnp.uint8(1)
+    """Insert ids (B, R) into the B filters. valid masks padding."""
+    p1, p2 = _positions(filt, ids)
+    # Invalid lanes write 0 under max: a no-op on their probe positions.
     if valid is not None:
-        # Redirect invalid lanes to a scatter position whose write is a no-op
-        # only if we write 0 -- instead keep position 0 but write the existing
-        # semantics: set bit only for valid lanes by writing max(old, v).
         v = valid.astype(jnp.uint8)
     else:
         v = jnp.ones_like(ids, jnp.uint8)
-    b = jnp.arange(filt.shape[0], dtype=jnp.int32)[:, None]
-    b = jnp.broadcast_to(b, ids.shape)
-    filt = filt.at[b, p1].max(v)
-    filt = filt.at[b, p2].max(v)
+    filt = filt.at[p1].max(v)
+    filt = filt.at[p2].max(v)
     return filt
 
 
 def bloom_query(filt: Array, ids: Array) -> Array:
-    """Membership test. (B, z), (B, R) -> (B, R) bool (True = maybe-seen)."""
-    z = filt.shape[-1]
-    p1, p2 = bloom_hashes(ids, z)
-    b = jnp.arange(filt.shape[0], dtype=jnp.int32)[:, None]
-    b = jnp.broadcast_to(b, ids.shape)
-    return (filt[b, p1] > 0) & (filt[b, p2] > 0)
+    """Membership test of ids (B, R) -> (B, R) bool (True = maybe-seen)."""
+    p1, p2 = _positions(filt, ids)
+    return (filt[p1] > 0) & (filt[p2] > 0)
 
 
 def bloom_query_and_set(filt: Array, ids: Array, valid: Array | None = None) -> tuple[Array, Array]:

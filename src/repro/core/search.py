@@ -140,7 +140,7 @@ class SearchResult(NamedTuple):
 
 class _State(NamedTuple):
     wl: Worklist
-    filt: Array             # bloom filter (B, z)
+    filt: Array             # bloom filters (B * z,), flat
     hist_ids: Array         # (B, C)
     hist_len: Array         # (B,)
     u: Array                # (B,) pending candidate (eagerly selected)

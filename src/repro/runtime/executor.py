@@ -26,7 +26,9 @@ the pipeline stays resident on the GPU across query batches):
     call of the compiled executable as operands. They are never captured as
     closure constants: jit embeds a captured array in the program itself,
     which at deployment size (GBs of adjacency) exhausts host memory at
-    compile time.
+    compile time. Adjacency and vectors are held as `core.rows.PackedRows`,
+    whose default layout the row gathers read in place: in the (n, w) form a
+    TPU lays them out column-major, and every call copied them whole.
   * **One `jax.jit` over stages 1+2+3.** PQ distance-table construction,
     graph traversal and re-ranking fuse into a single executable with the
     query buffer donated, so XLA schedules the whole pipeline end to end.
@@ -66,6 +68,7 @@ import numpy as np
 
 from repro.core import pq as pqlib
 from repro.core import rerank as rr
+from repro.core import rows as rowslib
 from repro.core import search as searchlib
 from repro.core.bang import SearchStats
 from repro.core.search import SearchConfig
@@ -130,6 +133,17 @@ class SearchHandle:
     compile_s: float    # compile time this dispatch paid (0 on cache hit)
 
 
+def _memory_figures(compiled) -> dict:
+    """Device bytes one call of `compiled` holds, from the compiler's own
+    `memory_analysis()` (also there for an executable loaded from the
+    persistent cache); empty where the backend gives none."""
+    mem = compiled.memory_analysis()
+    if mem is None:
+        return {}
+    return {"temp_bytes": int(mem.temp_size_in_bytes),
+            "argument_bytes": int(mem.argument_size_in_bytes)}
+
+
 class SearchExecutor:
     """Device-resident, jit-cached three-stage BANG search pipeline."""
 
@@ -140,9 +154,9 @@ class SearchExecutor:
         graph: VamanaGraph,
         *,
         variant: str = "inmem",
-        data_dev: Array | None = None,
+        data_dev: Array | rowslib.PackedRows | None = None,
         data_np: np.ndarray | None = None,
-        adjacency_dev: Array | None = None,
+        adjacency_dev: rowslib.PackedRows | None = None,
         min_bucket: int = 8,
         hostio: HostIOConfig | None = None,
         with_tombstones: bool = False,
@@ -161,7 +175,7 @@ class SearchExecutor:
         self._codebooks = jnp.asarray(codec.codebooks)
         self._codes = jnp.asarray(codes)
         self._graph = graph
-        self._data_dev = data_dev
+        self._data_dev = None if data_dev is None else rowslib.pack(data_dev)
         self._data_np = data_np
         self._hostio = hostio
         # Streaming mutability: tombstone-capable executables take a second
@@ -185,9 +199,9 @@ class SearchExecutor:
                 )
                 self._exchange = self.hostio_runtime.base_exchange()
         else:
-            self._adjacency = (
+            self._adjacency = rowslib.pack(
                 adjacency_dev if adjacency_dev is not None
-                else jnp.asarray(graph.adjacency)
+                else np.asarray(graph.adjacency)
             )
             self._adjacency_np = None
         self._init_serving_state(min_bucket, autotune)
@@ -233,7 +247,7 @@ class SearchExecutor:
         return len(self._cache)
 
     @property
-    def adjacency_dev(self) -> Array | None:
+    def adjacency_dev(self) -> rowslib.PackedRows | None:
         """Device adjacency, for sharing across same-index executors."""
         return self._adjacency
 
@@ -348,15 +362,19 @@ class SearchExecutor:
         tel = self.telemetry
         tr = None if tel is None else tel.tracer
         t0 = time.perf_counter()
-        with NO_SPAN if tr is None else tr.span(
+        with (NO_SPAN if tr is None else tr.span(
                 "compile", track="serve", bucket=bucket, k=k,
-                kernel_mode=cfg.kernel_mode), warnings.catch_warnings():
+                kernel_mode=cfg.kernel_mode)) as span, \
+                warnings.catch_warnings():
             # Donation is best-effort: when no output aliases the (bucket, d)
             # query buffer (small k), XLA reports it unusable. Expected.
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable"
             )
             compiled = self._compile(key, bucket, d, k, rerank, cfg)
+            mem = None if tel is None else _memory_figures(compiled)
+            if mem:
+                span.set(**mem)
         compile_s = time.perf_counter() - t0
         self.compile_s_total += compile_s
         self._cache[key] = compiled
@@ -365,12 +383,23 @@ class SearchExecutor:
                 "bang_serve_compile_seconds_total",
                 "wall seconds spent compiling search executables",
             ).inc(compile_s)
+            if mem:
+                tel.registry.gauge(
+                    "bang_serve_executable_temp_bytes",
+                    "device temporaries of the newest compiled executable",
+                ).set(mem["temp_bytes"])
         return compiled, compile_s
 
     def _compile(self, key, bucket: int, d: int, k: int, rerank: bool,
-                 cfg: SearchConfig):
-        """Trace + lower + compile one executable for `key` (subclass hook)."""
+                 cfg: SearchConfig, state: tuple | None = None):
+        """Trace + lower + compile one executable for `key` (subclass hook).
+
+        `state` replaces `_state()` as the index operands, for a compile
+        against shapes alone (`jax.ShapeDtypeStruct`s laid out like
+        `_state()`, e.g. for a described chip).
+        """
         variant = self.variant
+        state = self._state() if state is None else state
 
         def pipeline(queries: Array, state, tombstones: Array | None = None):
             # Trace-time side effect: runs once per compiled executable.
@@ -429,7 +458,7 @@ class SearchExecutor:
         if not self._with_tombstones:
             return (
                 jax.jit(pipeline, donate_argnums=0)
-                .lower(spec, self._state())
+                .lower(spec, state)
                 .compile()
             )
         # Tombstone-capable executable: the bitmap is a true operand (never a
@@ -438,7 +467,7 @@ class SearchExecutor:
         tomb_spec = jax.ShapeDtypeStruct((self._tombstone_len,), jnp.bool_)
         return (
             jax.jit(pipeline, donate_argnums=0)
-            .lower(spec, self._state(), tomb_spec)
+            .lower(spec, state, tomb_spec)
             .compile()
         )
 

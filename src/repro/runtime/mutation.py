@@ -61,6 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import pq as pqlib
+from repro.core import rows as rowslib
 from repro.core.bang import BangIndex
 from repro.core.search import SearchConfig
 from repro.core.vamana import VamanaGraph, greedy_search, robust_prune
@@ -667,7 +668,7 @@ class MutableBangIndex:
             codes=codes,
             graph=VamanaGraph(adjacency=adjacency, medoid=medoid),
             data_np=data,
-            data_dev=jnp.asarray(data),
+            data_dev=rowslib.pack(data),
         )
 
         # ---- atomic swap + reconciliation, under the lock ----------------

@@ -22,7 +22,9 @@ Span vocabulary (the names tests and docs pin):
     ``device``             async launch -> results on host; args: size,
                            bucket, compile_s
     ``compile``            executor cache miss (args: bucket, k,
-                           kernel_mode)
+                           kernel_mode, temp_bytes and argument_bytes:
+                           the executable's device temporaries and
+                           operands per call)
     ``drain``              one ServePipeline.drain() call; args: rows
     ``gc``                 a Python garbage collection during a drain;
                            args: generation, collected
@@ -78,6 +80,9 @@ class _NoSpan:
 
     def __enter__(self) -> "_NoSpan":
         return self
+
+    def set(self, **args) -> None:
+        return None
 
     def __exit__(self, *exc) -> None:
         return None

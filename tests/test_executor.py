@@ -254,3 +254,74 @@ def test_drain_requeues_queries_on_dispatch_error(small_ann_index):
     np.testing.assert_array_equal(ids, np.asarray(direct_ids))
     np.testing.assert_array_equal(dists, np.asarray(direct_dists))
     assert stats.queries == 40
+
+
+@pytest.mark.parametrize("variant", ["inmem", "exact"])
+def test_packed_index_state_answers_as_plain_rows_and_base(small_ann_index,
+                                                           variant):
+    """The in-memory executors hold adjacency and vectors as `PackedRows`
+    and answer bit for bit as the plain (n, w) rows they held before, and
+    (inmem) as base; a second executor on the index shares that state and
+    answers the same."""
+    import jax.numpy as jnp
+
+    from repro.core.rows import PackedRows
+
+    data, idx = small_ann_index
+    q = uniform_queries(data, 20, seed=81)
+    cfg = SearchConfig(t=32, bloom_z=8192)
+
+    def serve(ex):
+        with ServePipeline(ex, k=10, cfg=cfg, max_batch=8) as pipe:
+            pipe.submit(q)
+            ids, dists, _ = pipe.drain()
+        return np.asarray(ids), np.asarray(dists)
+
+    ex = SearchExecutor.from_index(idx, variant=variant)
+    assert isinstance(ex.adjacency_dev, PackedRows)
+    assert ex._data_dev is idx.data_dev            # packed once, by the index
+    got = serve(ex)
+
+    plain = SearchExecutor.from_index(idx, variant=variant)
+    plain._adjacency = jnp.asarray(idx.graph.adjacency)
+    plain._data_dev = jnp.asarray(idx.data_np)
+    for a, b in zip(got, serve(plain)):
+        np.testing.assert_array_equal(a, b)
+    if variant == "inmem":
+        for a, b in zip(got, serve(idx.executor("base"))):
+            np.testing.assert_array_equal(a, b)
+
+    other = SearchExecutor.from_index(idx, variant=variant,
+                                      adjacency_dev=ex.adjacency_dev)
+    assert other.adjacency_dev is ex.adjacency_dev
+    assert other._data_dev is ex._data_dev
+    for a, b in zip(got, serve(other)):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(got, serve(ex)):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("n,width", [(10, 64), (7, 96), (9, 3), (33, 17),
+                                     (16, 128), (5, 200)])
+def test_packed_rows_give_the_rows(n, width):
+    """Both gather forms of `PackedRows` return the rows, from a host or a
+    device array; lines are whole multiples of 128 lanes."""
+    import jax.numpy as jnp
+
+    from repro.core import rows
+
+    x = np.random.default_rng(n).standard_normal((n, width)).astype(np.float32)
+    ids = jnp.asarray(np.random.default_rng(1).integers(0, n, (4, 6)),
+                      jnp.int32)
+    for src in (x, jnp.asarray(x)):
+        packed = rows.pack(src)
+        assert packed.shape == (n, width)
+        assert packed.lines.shape[1] % rows.LANES == 0
+        assert rows.rows_per_line(width) in (1, 2, 4, 8)
+        for take in (rows.take_lines, rows.take_slots):
+            np.testing.assert_array_equal(
+                np.asarray(take(packed.lines, ids, width)),
+                x[np.asarray(ids)])
+        np.testing.assert_array_equal(np.asarray(packed[ids]),
+                                      x[np.asarray(ids)])
+    assert rows.pack(packed) is packed

@@ -345,6 +345,22 @@ def test_compile_cache_keys_identical_with_telemetry(small_ann_index):
         == CFG.kernel_mode
 
 
+@pytest.mark.parametrize("variant", ["inmem", "base"])
+def test_compile_span_and_gauge_carry_executable_memory(small_ann_index,
+                                                        variant):
+    """The compile span gives the new executable's device temporaries and
+    operands (`memory_analysis()`), and the gauge holds the temporaries."""
+    data, idx = small_ann_index
+    tel = Telemetry.create(trace=True)
+    ex = SearchExecutor.from_index(idx, variant=variant).set_telemetry(tel)
+    ex.search(np.asarray(data[:4] + 0.01, np.float32), K, cfg=CFG)
+    (span,) = [e for e in tel.tracer.events() if e["name"] == "compile"]
+    assert span["args"]["temp_bytes"] > 0
+    assert span["args"]["argument_bytes"] > 0
+    assert tel.registry.gauge("bang_serve_executable_temp_bytes").value \
+        == span["args"]["temp_bytes"]
+
+
 # ==================================================== pipeline: parity + window
 def test_pipeline_parity_and_window(small_ann_index):
     """Full-bundle serving is bit-exact vs detached, and the window adds up."""

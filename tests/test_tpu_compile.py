@@ -5,9 +5,10 @@ at the widths a SIFT1B-shaped deployment uses (d = 128, R = 64, m = 32,
 batches up to 1024, worklists up to 256) against a `v5e:2x2` topology
 description, so a kernel the chip's compiler refuses fails here, at no chip
 time. The reference mode's XLA ADC is compiled the same way, to check the
-form the chip gets. The topology is described inside a fixture (never at import): only one
-process may load the TPU compiler library, and every test worker imports
-this file.
+form the chip gets, and so is the in-memory executor's whole pipeline at
+the DEEP1B cell's shapes, against index shapes alone. The topology is
+described inside a fixture (never at import): only one process may load
+the TPU compiler library, and every test worker imports this file.
 """
 import os
 
@@ -146,3 +147,46 @@ def test_reference_adc_compiles_without_gather(one_chip, no_cache):
     compiled = pq.adc_distance.lower(*args).compile()
     assert "gather(" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+N_CELL, D_CELL, T_CELL, K_CELL = 10_000_000, 96, 32, 10
+INDEX_SHAPES = ("[10000000,64]", "[10000000,96]", "[10000000,32]",
+                "[5000000,128]", "[2500000,384]")
+
+
+@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
+@pytest.mark.parametrize("variant", ["inmem", "exact"])
+def test_in_memory_executable_holds_its_index_once(one_chip, no_cache, variant):
+    """The executor's own pipeline at the DEEP1B cell's shapes (N 10M, d 96,
+    R 64, m 32, B 1024, t 32, k 10), compiled against shapes alone: no call
+    copies the graph, the vectors or the codes, and the temporaries stay
+    under 1 GiB (a column-major (N, 64) graph and (N, 96) vectors were
+    copied whole on every call: 6.38 GB)."""
+    import numpy as np
+
+    from repro.core import pq, rows
+    from repro.core.search import SearchConfig
+    from repro.core.vamana import VamanaGraph
+    from repro.runtime.executor import SearchExecutor
+
+    small = 64
+    graph = VamanaGraph(
+        adjacency=np.random.default_rng(0).integers(
+            0, small, (small, R)).astype(np.int32), medoid=0)
+    ex = SearchExecutor(
+        pq.PQCodec(jnp.zeros((M, 256, D_CELL // M))),
+        jnp.zeros((small, M), jnp.uint8), graph, variant=variant,
+        data_dev=jnp.zeros((small, D_CELL)))
+    f32 = jnp.float32
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        (jax.ShapeDtypeStruct((M, 256, D_CELL // M), f32),
+         jax.ShapeDtypeStruct((N_CELL, M), jnp.uint8),
+         jax.eval_shape(rows.pack, jax.ShapeDtypeStruct((N_CELL, R), jnp.int32)),
+         jax.eval_shape(rows.pack, jax.ShapeDtypeStruct((N_CELL, D_CELL), f32))))
+    compiled = ex._compile("cell", B, D_CELL, K_CELL, True,
+                           SearchConfig(t=T_CELL), state=state)
+    copies = [line for line in compiled.as_text().splitlines()
+              if " copy(" in line and any(s in line for s in INDEX_SHAPES)]
+    assert not copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
