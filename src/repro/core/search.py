@@ -140,7 +140,7 @@ class SearchResult(NamedTuple):
 
 class _State(NamedTuple):
     wl: Worklist
-    filt: Array             # bloom filters (B * z,), flat
+    filt: bloomlib.BloomFilters  # the batch's bloom filters, packed
     hist_ids: Array         # (B, C)
     hist_len: Array         # (B,)
     u: Array                # (B,) pending candidate (eagerly selected)

@@ -4,8 +4,8 @@ The search runs each stage of Algorithm 2 under a named scope
 (`repro.core.search.STAGES`), and JAX writes the scope path into each op's
 `op_name` metadata. The compiler keeps that metadata on most ops, but not
 on all: a TPU fusion carries it only on the instructions inside its fused
-computation, and the copies and relayout loops the compiler inserts (the
-bloom filter's (B, z) state is relaid out for its scatters) carry none. So
+computation, and the copies and relayout loops the compiler inserts carry
+none. So
 an op's stage is, in this order:
 
   1. the innermost stage scope in its own `op_name` (innermost, because the
