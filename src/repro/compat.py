@@ -6,7 +6,7 @@ out itself, so one place fixes the choices: meshes with Auto axis types,
 called once per batch member (never claimed to vectorize).
 
 Keep this module dependency-free (jax only): it is imported by `core`,
-`launch`, `models`, tests and subprocess snippets alike.
+`runtime`, `chip_smoke.py`, tests and subprocess snippets alike.
 """
 from __future__ import annotations
 

@@ -1,4 +1,4 @@
-"""Pod-scale BANG: the sharded-graph search (DESIGN.md §2, §6).
+"""Pod-scale BANG: the sharded-graph search.
 
 The paper keeps the graph + full vectors in host RAM (far memory) and the PQ
 codes in GPU HBM (near memory), moving only O(frontier) bytes per hop over

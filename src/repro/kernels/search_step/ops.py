@@ -19,7 +19,7 @@ Beyond VMEM: `codes_resident` decides, per codes block, whether the
 fused kernels keep the packed lines VMEM-resident or leave them in HBM and
 fetch each candidate's line by DMA. The decision point is the VMEM
 budget (`vmem_budget_bytes`, overridable via the REPRO_VMEM_BUDGET env var so
-tests and benchmarks can force the DMA path on small blocks), or an explicit
+tests can force the DMA path on small blocks), or an explicit
 `SearchConfig.codes_tile_rows` > 0, which forces the HBM placement. The DMA
 path fetches rows, not tiles, so the value only selects the placement.
 Either way `kernel_mode="fused"` never falls back to the staged path.

@@ -1,4 +1,4 @@
-# Runtime subsystem: resident serving executors + the LM training loop.
+# Runtime subsystem: resident serving executors and what serves them.
 #   executor    -- jit-cached, shape-bucketed three-stage search pipeline (1 device)
 #   sharded     -- the same contract over a device mesh (graph > one device)
 #   serving     -- streaming micro-batch serve loop with double buffering
@@ -27,4 +27,3 @@ from .telemetry import (  # noqa: F401
     Telemetry,
     Tracer,
 )
-from .train_loop import TrainLoopConfig, train_loop  # noqa: F401

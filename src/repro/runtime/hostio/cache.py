@@ -99,21 +99,6 @@ class HotAdjacencyCache:
         """Bytes this cache pins on device (rows + id->slot map)."""
         return int(self._rows.nbytes + self._slot_of.nbytes)
 
-    def covers(self, ids) -> np.ndarray:
-        """Host-side membership mask: which of `ids` are pinned on device.
-
-        Pure introspection (numpy in, numpy out; no device traffic) for the
-        degraded-serving story: when a host partition is down, lanes this
-        mask covers are still served bit-exactly from the device copy, so
-        `covers(partition_ids).mean()` bounds the recall a dead partition
-        can cost. Used by tests/test_resilience.py and bench_faults.py to
-        report cache coverage next to measured degraded recall.
-        """
-        ids = np.asarray(ids)
-        slot_of = np.asarray(self._slot_of)
-        valid = (ids >= 0) & (ids < self.n)
-        return valid & (slot_of[np.clip(ids, 0, self.n - 1)] >= 0)
-
     # ------------------------------------------------------------- mutation
     def refresh(self, adjacency: np.ndarray) -> None:
         """Re-upload the pinned rows from a mutated adjacency (same hot set).

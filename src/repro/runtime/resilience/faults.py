@@ -127,7 +127,7 @@ class FaultInjector:
 
     Thread-safe: ordinal bookkeeping runs under a private lock; sleeps and
     raises happen outside it. `injected()` reports how many events each
-    kind actually fired -- the benchmarks put those numbers next to the
+    kind actually fired, so a caller can put those numbers next to the
     recall/latency impact they caused.
     """
 

@@ -15,8 +15,7 @@ seams (see `NeighborService._account`) and records, per hop:
 and, from kernel metadata the executor stamps at dispatch time
 (`set_kernel_info`), the analytic codes-stream bytes per hop
 (`repro.kernels.search_step.ops.hbm_codes_stream_bytes_per_hop`) so the
-summary reports measured per-hop wall next to the modeled HBM traffic --
-the same pairing `bench_kernels.py` prints for the beyond-VMEM lane.
+summary reports measured per-hop wall next to the modeled HBM traffic.
 
 Device timelines get their names elsewhere: the tracer's spans enter
 `jax.profiler.TraceAnnotation`s (`tracing.py`), and the search's stages

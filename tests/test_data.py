@@ -1,47 +1,7 @@
-"""Data pipeline determinism + skip-ahead (fault-tolerance substrate)."""
+"""Synthetic vector datasets: shapes, dtype and finiteness."""
 import numpy as np
 
-from repro.data import TokenStream, gaussian_mixture, uniform_queries
-
-
-def test_batches_deterministic():
-    s1 = TokenStream(1000, 32, 4, seed=5)
-    s2 = TokenStream(1000, 32, 4, seed=5)
-    b1, b2 = s1.batch_at(17), s2.batch_at(17)
-    np.testing.assert_array_equal(b1["tokens"], b2["tokens"])
-    np.testing.assert_array_equal(b1["labels"], b2["labels"])
-
-
-def test_batches_differ_across_steps_and_shards():
-    s = TokenStream(1000, 32, 4, seed=5)
-    assert not np.array_equal(s.batch_at(0)["tokens"], s.batch_at(1)["tokens"])
-    sh0 = TokenStream(1000, 32, 8, seed=5, shard=0, n_shards=2)
-    sh1 = TokenStream(1000, 32, 8, seed=5, shard=1, n_shards=2)
-    assert not np.array_equal(sh0.batch_at(0)["tokens"], sh1.batch_at(0)["tokens"])
-    assert sh0.batch_at(0)["tokens"].shape == (4, 32)
-
-
-def test_labels_are_next_tokens():
-    s = TokenStream(1000, 32, 2, seed=1)
-    b = s.batch_at(3)
-    # labels[i] == tokens[i+1] by construction of the shared (seq+1) buffer
-    full_first = b["tokens"][0, 1:]
-    np.testing.assert_array_equal(full_first, b["labels"][0, :-1])
-
-
-def test_prefetch_matches_direct():
-    s = TokenStream(500, 16, 2, seed=2)
-    gen = s.prefetch(start_step=4)
-    step, batch = next(gen)
-    assert step == 4
-    np.testing.assert_array_equal(batch["tokens"], s.batch_at(4)["tokens"])
-    gen.close()
-
-
-def test_frontend_embeds():
-    s = TokenStream(500, 16, 2, seed=2, frontend=(6, 32))
-    b = s.batch_at(0)
-    assert b["frontend"].shape == (2, 6, 32)
+from repro.data import gaussian_mixture, uniform_queries
 
 
 def test_vector_datasets():

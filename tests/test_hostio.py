@@ -9,7 +9,7 @@ placements (base / sharded-base) under every kernel mode. The subsystem may
 change where bytes flow and when gathers run, never what comes back.
 
 In-process tests adapt to however many devices the process has (1 in the
-default tier-1 run; >1 under the CI multidevice job); the `slow` subprocess
+default tier-1 run; >1 under the CI multidevice job); the subprocess
 tests force 1/2/4 host devices explicitly.
 """
 import os
@@ -539,27 +539,6 @@ def test_result_cache_disabled_by_default(small_ann_index):
         ServePipeline(idx.executor("inmem"), result_cache_size=-1)
 
 
-# ------------------------------------------------------- bench row schema
-def test_bench_hostio_row_json_schema(hostio_setup):
-    import json
-
-    data, idx, ex = hostio_setup
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)   # benchmarks/ lives next to src/, not in it
-    from benchmarks.bench_hostio import HOSTIO_ROW_SCHEMA, hostio_row
-
-    ex.search(uniform_queries(data, 16, seed=102), 5,
-              cfg=SearchConfig(t=32, bloom_z=8192))
-    row = hostio_row("hostio_base_w2_c64_p1", ex, 0.99, 1234.5, 810.0, 2.5)
-    assert set(row) == set(HOSTIO_ROW_SCHEMA)
-    assert row == json.loads(json.dumps(row))
-    assert row["variant"] == "base" and row["workers"] == FULL.workers
-    assert row["prefetch"] is True
-    assert row["hot_cache_hit_rate"] > 0
-    assert row["host_bytes_saved_per_hop"] > 0
-    assert row["overlap_fraction"] > 0
-
-
 # ------------------------------------------- forced-device subprocesses
 def _run(code: str, devices: int) -> str:
     env = dict(os.environ)
@@ -620,7 +599,6 @@ print("OK", devices)
 """
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize(
     "devices,mesh_shape", [(1, (1, 1)), (2, (1, 2)), (4, (2, 2))]
 )
@@ -629,7 +607,6 @@ def test_hostio_sharded_base_parity_forced_devices(devices, mesh_shape):
     assert f"OK {devices}" in out
 
 
-@pytest.mark.slow
 def test_hostio_model_only_mesh_four_devices():
     """All four devices on `model`: four host partitions, four worker pools,
     four prefetch ticket streams -- zero device adjacency."""

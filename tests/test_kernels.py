@@ -1,9 +1,6 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret) vs ref.py oracles, plus
 the fused search_step megakernel (unit, property, and executor-level parity
 across kernel_mode x batch bucket x serving variant)."""
-import os
-import sys
-
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -15,8 +12,6 @@ try:
     from hypothesis import given, settings, strategies as st
 except ImportError:
     from _hypothesis_compat import given, settings, strategies as st
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 KERNEL_MODES = ("reference", "staged", "fused")
 
@@ -261,35 +256,6 @@ def test_hbm_accounting_fused_strictly_fewer():
     assert staged >= B * R * m * 4
 
 
-def test_bench_kernel_row_json_schema():
-    """bench_kernels' executor-lane rows: schema + fused < staged traffic."""
-    import json
-
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)   # benchmarks/ lives next to src/, not in it
-    from benchmarks.bench_kernels import KERNEL_ROW_SCHEMA, kernel_row
-
-    rows = {
-        mode: kernel_row(
-            f"exec_inmem_{mode}_b16", mode, "inmem", 12, 16,
-            qps=100.0, us_per_query=10.0, per_hop_us=1.0, n_iters=32,
-            R=16, m=8, compile_s=1.0, t=16,
-        )
-        for mode in KERNEL_MODES
-    }
-    for row in rows.values():
-        assert set(row) == set(KERNEL_ROW_SCHEMA)
-        assert row == json.loads(json.dumps(row))
-    assert (
-        rows["fused"]["hbm_candidate_roundtrips_per_hop"]
-        < rows["staged"]["hbm_candidate_roundtrips_per_hop"]
-    )
-    assert (
-        rows["fused"]["hbm_intermediate_bytes_per_hop"]
-        < rows["staged"]["hbm_intermediate_bytes_per_hop"]
-    )
-
-
 # ------------------------------------------------ beyond-VMEM DMA pipeline
 def test_resolve_codes_tiling_policy(monkeypatch):
     from repro.kernels.search_step import ops
@@ -435,13 +401,3 @@ def test_hbm_codes_stream_accounting():
     assert ops.hbm_codes_stream_bytes_per_hop("fused", B, n, m, R, 64) == (
         B * R * 512
     )
-
-
-def test_bench_beyond_vmem_row_json_schema():
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)
-    from benchmarks.bench_kernels import BEYOND_VMEM_ROW_SCHEMA
-
-    assert {"per_hop_us", "codes_tile_rows", "num_tiles",
-            "vmem_budget_bytes", "hbm_codes_stream_bytes_per_hop",
-            } <= set(BEYOND_VMEM_ROW_SCHEMA)

@@ -350,16 +350,3 @@ def test_mutation_counters_in_exchange_and_stats(mut_base):
     s = mut.mutation_stats()
     assert s["epoch"] == 2 and s["generation"] == 0
     assert s["tombstones"] == 2 and s["delta_total"] == 3
-
-
-def test_bench_mutation_row_schema():
-    from benchmarks.bench_mutation import MUTATION_ROW_SCHEMA, mutation_row
-
-    row = mutation_row(
-        name="x", phase="steady_mixed", variant="inmem", recall=0.97,
-        qps=123.4, us_per_query=8.1, compile_s=0.5,
-        stats={"epoch": 3, "generation": 1, "consolidations": 1,
-               "tombstones": 2, "tombstone_fraction": 0.01,
-               "delta_points": 4, "delta_total": 5, "base_n": 200},
-    )
-    assert set(row) == set(MUTATION_ROW_SCHEMA)

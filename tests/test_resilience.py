@@ -20,7 +20,6 @@ Three layers under test:
 Determinism: every injector here is seeded and window-scripted, so counter
 assertions are exact, not thresholds.
 """
-import dataclasses
 import os
 import subprocess
 import sys
@@ -567,53 +566,6 @@ def test_scripted_fault_schedule_degraded_recall_and_recovery(
         pipe.close()
 
 
-# ------------------------------------------------------- bench row schema
-def test_bench_faults_row_json_schema():
-    import json
-
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)   # benchmarks/ lives next to src/, not in it
-    from benchmarks.bench_faults import FAULT_ROW_SCHEMA, fault_row
-
-    from repro.runtime.serving import ServeStats
-
-    stats = ServeStats(
-        batches=1, queries=16, wall_s=0.1, compile_s=0.0, qps=160.0,
-        p50_ms=1.0, p95_ms=2.5, mean_recall=0.9125, shed_queries=4,
-        expired_queries=1,
-        hostio={"degraded_lanes": 3, "retries": 2, "hedged_gathers": 1,
-                "failover_gathers": 0, "worker_deaths": 0,
-                "deadline_hits": 0, "partitions_down": 1},
-    )
-    row = fault_row("degraded", stats, bit_exact=False, compile_s=1.5)
-    assert set(row) == set(FAULT_ROW_SCHEMA)
-    assert row == json.loads(json.dumps(row))
-    assert row["phase"] == "degraded" and row["name"].endswith("degraded")
-    assert row["shed_rate"] == pytest.approx(4 / 20)
-    assert row["recall"] == pytest.approx(0.9125)
-    assert row["degraded_lanes"] == 3 and row["partitions_down"] == 1
-    assert row["bit_exact_vs_healthy"] is False
-    # No Telemetry bundle attached -> the block is None, schema unchanged.
-    assert row["telemetry"] is None
-
-    # With a registry window attached the block summarises it compactly.
-    stats_t = dataclasses.replace(stats, telemetry={
-        "bang_serve_queries_total": {"type": "counter", "value": 16.0},
-        "bang_serve_shed_total": {"type": "counter", "value": 4.0},
-        "bang_serve_latency_seconds": {
-            "type": "histogram", "count": 16, "sum": 0.02, "buckets": {},
-        },
-        "bang_hostio_degraded_lanes_total": {"type": "counter", "value": 3.0},
-    })
-    row_t = fault_row("degraded", stats_t, bit_exact=False, compile_s=1.5)
-    assert set(row_t) == set(FAULT_ROW_SCHEMA)
-    assert row_t == json.loads(json.dumps(row_t))
-    t = row_t["telemetry"]
-    assert t["queries"] == 16.0 and t["shed"] == 4.0
-    assert t["latency_obs"] == 16 and t["degraded_lanes"] == 3.0
-    assert t["expired"] == 0 and t["hostio_requests"] == 0
-
-
 # ------------------------------------------- forced-device subprocesses
 def _run(code: str, devices: int) -> str:
     env = dict(os.environ)
@@ -668,7 +620,6 @@ print("SHARDED-FAILOVER-OK")
 """
 
 
-@pytest.mark.slow
 def test_sharded_base_failover_parity_two_devices():
     out = _run(FAILOVER_CODE.format(devices=2, mesh_shape=(1, 2)), 2)
     assert "SHARDED-FAILOVER-OK" in out

@@ -7,7 +7,7 @@ return bit-exact ids AND distances vs both single-device variants ("base",
 "inmem") and vs the device-sharded "sharded" variant -- moving the graph to
 host RAM may change where bytes flow, never what comes back. The in-process
 tests adapt to however many devices the process has (1 in the default tier-1
-run; >1 under the CI multidevice job's XLA_FLAGS); the `slow` subprocess
+run; >1 under the CI multidevice job's XLA_FLAGS); the subprocess
 tests force 1/2/4 host devices and a model-only mesh explicitly.
 """
 import os
@@ -195,29 +195,6 @@ def test_single_device_executor_accounting(small_ann_index):
     assert idx.executor("inmem").exchange_bytes_per_hop(16)["host_link_bytes"] == 0
 
 
-def test_bench_sharded_row_json_schema(sharded_base_setup):
-    """bench_qps_recall's JSON rows carry the host-link-bytes fields."""
-    import json
-
-    _, idx, mesh, ex = sharded_base_setup
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)   # benchmarks/ lives next to src/, not in it
-    from benchmarks.bench_qps_recall import SHARDED_ROW_SCHEMA, sharded_row
-
-    row = sharded_row("fig9_sharded_base_d1", ex, 1, 0.99, 1234.5, 810.0, 2.5)
-    assert set(row) == set(SHARDED_ROW_SCHEMA)
-    assert {"host_link_bytes_per_hop", "host_ids_out_bytes_per_hop",
-            "host_rows_in_bytes_per_hop",
-            "collective_bytes_per_hop"} <= set(row)
-    assert row == json.loads(json.dumps(row)), "row must be JSON round-trippable"
-    assert row["variant"] == "sharded-base" and row["host_link_bytes_per_hop"] > 0
-    dev_row = sharded_row(
-        "fig9_sharded_d1", idx.executor("sharded", mesh=mesh), 1, 0.99, 1.0, 1.0, 0.0
-    )
-    assert set(dev_row) == set(SHARDED_ROW_SCHEMA)
-    assert dev_row["host_link_bytes_per_hop"] == 0
-
-
 # ------------------------------------------------------ ownership property
 class _RecordingPartition(np.ndarray):
     """ndarray view logging every row-index array used to gather from it --
@@ -334,7 +311,6 @@ print("OK", devices)
 """
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize(
     "devices,mesh_shape", [(1, (1, 1)), (2, (1, 2)), (4, (2, 2))]
 )
@@ -343,7 +319,6 @@ def test_sharded_base_parity_forced_devices(devices, mesh_shape):
     assert f"OK {devices}" in out
 
 
-@pytest.mark.slow
 def test_sharded_base_model_only_mesh_four_devices():
     """All four devices on `model`: four host graph partitions, one callback
     each -- the graph-bigger-than-one-device shape with zero device adjacency."""

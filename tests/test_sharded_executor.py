@@ -4,7 +4,7 @@ invariant the owner-shard collectives rest on.
 
 The in-process tests adapt to however many devices the process has (1 in the
 default tier-1 run; >1 under the CI multidevice job's
-XLA_FLAGS=--xla_force_host_platform_device_count). The `slow` subprocess
+XLA_FLAGS=--xla_force_host_platform_device_count). The subprocess
 tests force 1/2/4 host devices explicitly, proving parity holds on real
 multi-device meshes regardless of the parent's device count.
 """
@@ -237,7 +237,6 @@ print("OK", devices)
 """
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize(
     "devices,mesh_shape", [(1, (1, 1)), (2, (1, 2)), (4, (2, 2))]
 )
@@ -246,7 +245,6 @@ def test_sharded_executor_parity_forced_devices(devices, mesh_shape):
     assert f"OK {devices}" in out
 
 
-@pytest.mark.slow
 def test_sharded_model_only_mesh_four_devices():
     """All four devices on `model` -- the graph-bigger-than-one-device shape."""
     out = _run(PARITY_CODE.format(devices=4, mesh_shape=(1, 4)), 4)

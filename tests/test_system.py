@@ -23,7 +23,6 @@ def test_full_pipeline_three_stages(small_ann_index):
     assert stats.qps > 0 and stats.n_iters > 0
 
 
-@pytest.mark.slow
 def test_compression_ratio_recall_tradeoff():
     """Paper Fig 9: recall stable until aggressive compression, then drops."""
     data = gaussian_mixture(1200, 32, n_clusters=16, seed=21)

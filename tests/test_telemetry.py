@@ -6,8 +6,8 @@ The two contracts that matter most are test-pinned here:
     executor's compile-cache keys, never retraces, and returns bit-identical
     ids/dists vs the detached pipeline (`test_compile_cache_keys_identical_
     with_telemetry`, `test_pipeline_parity_and_window`).
-  * **Total request attribution.** Over the bench_faults fault-injection
-    schedule with tracing on, every submitted query lands on the Chrome
+  * **Total request attribution.** Over a scripted fault-injection
+    schedule (`build_schedule`) with tracing on, every submitted query lands on the Chrome
     trace timeline exactly once -- served, cache_hit, shed or expired; zero
     unattributed -- and the flight recorder emits a postmortem for every
     injected failover/degrade transition
@@ -15,7 +15,6 @@ The two contracts that matter most are test-pinned here:
 """
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -30,7 +29,12 @@ from repro.runtime import (
     Tracer,
 )
 from repro.runtime.hostio import HostIOConfig
-from repro.runtime.resilience import ResilienceConfig
+from repro.runtime.resilience import (
+    FOREVER,
+    FaultInjector,
+    FaultSpec,
+    ResilienceConfig,
+)
 from repro.runtime.telemetry import (
     LATENCY_BUCKETS_S,
     FlightRecorder,
@@ -40,8 +44,6 @@ from repro.runtime.telemetry import (
     validate_chrome_trace,
 )
 from repro.runtime.telemetry.registry import Histogram
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 K = 5
 CFG = SearchConfig(t=16)
@@ -422,18 +424,50 @@ def test_pipeline_parity_and_window(small_ann_index):
 
 
 # ============================================ acceptance: fault schedule
+def build_schedule(svc, *, seed: int = 7) -> list:
+    """The scripted fault schedule: [(phase, setup, teardown), ...].
+
+    The same query batch replays through every phase so bit-exactness vs
+    the healthy phase is checkable; `svc` is the executor's
+    `NeighborService`. Health transitions are scripted, never inferred: the
+    caller's `ResilienceConfig` sets `unhealthy_after` effectively infinite
+    and `auto_failover` off, so every phase boundary is an explicit
+    `mark_partition_down` / `fail_over` / `recover` call.
+    """
+    def _inject(*specs):
+        svc.set_injector(FaultInjector(specs, seed=seed))
+
+    return [
+        ("healthy", lambda: None, lambda: None),
+        # count=2, not FOREVER: the retry budget (max_retries=3) must be
+        # able to absorb every injected failure or lanes would degrade and
+        # break the phase's bit-exactness.
+        ("transient",
+         lambda: _inject(FaultSpec("transient_error", shard=0, count=2)),
+         lambda: svc.set_injector(None)),
+        # Stall (0.15 s) > hedge budget (0.05 s): every stalled pooled
+        # gather / ticket is abandoned and re-gathered inline, bit-exact.
+        ("stalled",
+         lambda: _inject(FaultSpec("worker_stall", stall_s=0.15,
+                                   count=FOREVER)),
+         lambda: svc.set_injector(None)),
+        ("degraded",
+         lambda: svc.mark_partition_down(0), lambda: None),
+        ("failover",
+         lambda: svc.fail_over(0), lambda: None),
+        ("recovered",
+         lambda: svc.recover(0), lambda: None),
+    ]
+
+
 def test_trace_attribution_over_fault_schedule(small_ann_index):
-    """Drive the bench_faults schedule with tracing + flight recording on.
+    """Drive `build_schedule` with tracing + flight recording on.
 
     Acceptance contract: every submitted query is attributed on the trace
     timeline exactly once (served / cache_hit / shed / expired -- zero
     unattributed), and the flight recorder emits a postmortem per injected
     failover/degrade transition.
     """
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)   # benchmarks/ lives next to src/, not in it
-    from benchmarks.bench_faults import build_schedule
-
     data, idx = small_ann_index
     q = np.asarray(data[:12] + 0.02, np.float32)
     gt = np.asarray(brute_force_knn(data, q, K))
