@@ -27,16 +27,19 @@ from .worklist import INVALID_ID
 Array = jax.Array
 
 
-# Per-callback result budget for the host gather, in bytes. XLA:CPU farms any
-# op touching >=128 KiB out to its intra-op threadpool; on a low-core host the
-# pool's only thread can be the one parked inside the host callback, so a
-# callback result that large, consumed by a parallelised kernel, deadlocks the
-# runtime. Half the threshold keeps every chunk (and its consumer) inline.
+# Per-callback result budget for the host gather on XLA:CPU, in bytes. XLA:CPU
+# farms any op touching >=128 KiB out to its intra-op threadpool; on a low-core
+# host the pool's only thread can be the one parked inside the host callback,
+# so a callback result that large, consumed by a parallelised kernel,
+# deadlocks the runtime. Half the threshold keeps every chunk (and its
+# consumer) inline. On the TPU the result's consumer is a device op, so the
+# cap does not apply there, and every callback would cost a round trip of its
+# own: the whole (B, C) block goes out in one.
 _GATHER_CHUNK_BYTES = 64 * 1024
 
 
 def gather_chunk_rows(d: int) -> int:
-    """Rows of width `d` per host-gather callback (see _GATHER_CHUNK_BYTES)."""
+    """Rows of width `d` per XLA:CPU host-gather callback (_GATHER_CHUNK_BYTES)."""
     return max(1, _GATHER_CHUNK_BYTES // (d * 4))
 
 
@@ -46,10 +49,12 @@ def gather_host_vectors(
 ) -> Array:
     """Host-side candidate-vector service (BANG Base link traffic).
 
-    The gather is issued as a sequence of bounded-size pure_callbacks rather
-    than one bulk transfer, mirroring the paper's batched candidate shipping
-    (§4.9) and keeping each result under XLA:CPU's parallel-consumer
-    threshold (see _GATHER_CHUNK_BYTES).
+    The gather's pure_callbacks follow the platform the call is lowered for
+    (`jax.lax.platform_dependent`): on the TPU one callback returns the
+    whole block; elsewhere a sequence of bounded-size ones, each result
+    under XLA:CPU's parallel-consumer threshold (see _GATHER_CHUNK_BYTES).
+    An explicit `chunk_rows` chunks on every platform. Every form runs the
+    same host gather, so the vectors are the same bits.
 
     `tracer` returns the telemetry `Tracer` to record into, or None. Each
     callback body calls it, so attaching a tracer later changes no traced
@@ -71,22 +76,27 @@ def gather_host_vectors(
             with tr.span("rerank_gather", track="rerank", rows=int(idx.size)):
                 return gather(idx)
 
-    if chunk_rows is None:
-        chunk_rows = gather_chunk_rows(d)
-    flat = ids.reshape(-1)
-    total = flat.shape[0]
-    if total <= chunk_rows:
-        shape = jax.ShapeDtypeStruct((*ids.shape, d), jnp.float32)
-        return pure_callback(host_gather, shape, ids)
-    pieces = [
-        pure_callback(
-            host_gather,
-            jax.ShapeDtypeStruct((min(chunk_rows, total - s), d), jnp.float32),
-            flat[s : s + chunk_rows],
-        )
-        for s in range(0, total, chunk_rows)
-    ]
-    return jnp.concatenate(pieces, 0).reshape(*ids.shape, d)
+    def gather_chunks(ids: Array, rows: int) -> Array:
+        flat = ids.reshape(-1)
+        total = flat.shape[0]
+        if total <= rows:
+            shape = jax.ShapeDtypeStruct((*ids.shape, d), jnp.float32)
+            return pure_callback(host_gather, shape, ids)
+        pieces = [
+            pure_callback(
+                host_gather,
+                jax.ShapeDtypeStruct((min(rows, total - s), d), jnp.float32),
+                flat[s : s + rows],
+            )
+            for s in range(0, total, rows)
+        ]
+        return jnp.concatenate(pieces, 0).reshape(*ids.shape, d)
+
+    if chunk_rows is not None:
+        return gather_chunks(ids, chunk_rows)
+    return jax.lax.platform_dependent(
+        ids, tpu=lambda i: gather_chunks(i, i.size),
+        default=lambda i: gather_chunks(i, gather_chunk_rows(d)))
 
 
 def exact_topk(
@@ -130,8 +140,8 @@ def rerank(
     """Full re-rank stage: gather candidate vectors, exact top-k.
 
     Exactly one of data (device) / data_np (host) must be provided. Host
-    gathers are transparently chunked (see gather_host_vectors, which also
-    says what `tracer` does).
+    gathers take one callback on the TPU and 64 KiB chunks elsewhere (see
+    gather_host_vectors, which also says what `tracer` does).
     """
     assert (data is None) != (data_np is None)
     with jax.named_scope("bang.rerank"):

@@ -100,3 +100,39 @@ def test_larger_t_does_not_reduce_recall(small_ann_index, queries):
         r.append(recall_at_k(np.asarray(ids), gt))
     assert r[-1] >= r[0] - 1e-9
     assert r[-1] >= 0.9
+
+
+@pytest.mark.parametrize("chunk_rows", [7, 64])
+def test_host_gather_one_callback_matches_chunks(chunk_rows):
+    """The TPU's form of the Base re-rank gather, the whole (B, C) block in
+    one callback, returns the same vectors and the same re-ranked (ids,
+    dists) as the chunked form XLA:CPU takes, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import rerank as rr
+    from repro.core.worklist import INVALID_ID
+
+    rng = np.random.default_rng(3)
+    b, c, d, k = 8, 24, 32, 5                 # 24 KiB: safe in one callback
+    data = rng.standard_normal((300, d)).astype(np.float32)
+    q = jnp.asarray(rng.standard_normal((b, d)).astype(np.float32))
+    ids_np = rng.permuted(np.tile(np.arange(300, dtype=np.int32), (b, 1)),
+                          axis=1)[:, :c]
+    ids_np[:, -3:] = int(INVALID_ID)
+    ids = jnp.asarray(ids_np)
+
+    def run(rows):
+        def fn(q, ids):
+            vecs = rr.gather_host_vectors(data, ids, chunk_rows=rows)
+            return (vecs, *rr.exact_topk(q, vecs, ids, k))
+        text = jax.jit(fn).lower(q, ids).as_text()
+        return text.count("xla_ffi_python_cpu_callback"), jax.jit(fn)(q, ids)
+
+    n_one, one = run(b * c)
+    n_chunked, chunked = run(chunk_rows)
+    assert (n_one, n_chunked) == (1, -(-b * c // chunk_rows))
+    for x, y in zip(one, chunked):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    safe = np.where(ids_np == int(INVALID_ID), 0, ids_np)
+    np.testing.assert_array_equal(np.asarray(one[0]), data[safe])

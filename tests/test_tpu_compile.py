@@ -7,7 +7,8 @@ description, so a kernel the chip's compiler refuses fails here, at no chip
 time. The reference mode's XLA ADC and the bloom filters' test-and-set are
 compiled the same way, to check the form the chip gets, and so is the
 in-memory executor's whole pipeline at the DEEP1B cell's shapes, against
-index shapes alone. The topology is
+index shapes alone; Base's host re-rank gather is lowered for the chip and
+for the CPU, to count its callbacks on each. The topology is
 described inside a fixture (never at import): only one process may load
 the TPU compiler library, and every test worker imports this file.
 """
@@ -216,3 +217,25 @@ def test_in_memory_executable_holds_its_index_once(one_chip, no_cache, variant):
               if " copy(" in line and any(s in line for s in INDEX_SHAPES)]
     assert not copies
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_rerank_gather_callbacks_per_platform(request, platform):
+    """Base's re-rank gather at the cell's shapes ((1024, 56) ids, d 96):
+    the chip gets the whole 22 MB block in one host transfer (one send, one
+    recv); the CPU keeps 64 KiB chunks, 170 rows each: 338 callbacks."""
+    import numpy as np
+
+    from repro.core.rerank import gather_host_vectors
+
+    data = np.zeros((64, D_CELL), np.float32)
+    sharding = request.getfixturevalue("one_chip") if platform == "tpu" else None
+    ids = jax.ShapeDtypeStruct((B, 56), jnp.int32, sharding=sharding)
+    text = jax.jit(lambda i: gather_host_vectors(data, i)).lower(ids).as_text()
+    if platform == "tpu":
+        assert text.count("stablehlo.send") == 1
+        assert text.count("stablehlo.recv") == 1
+        assert "tensor<1024x56x96xf32>" in text
+    else:
+        assert text.count("stablehlo.custom_call @xla_ffi_python_cpu_callback") == 338
+        assert "stablehlo.recv" not in text
