@@ -51,8 +51,12 @@ Stage scopes: each stage runs under a `jax.named_scope` from `STAGES`, the
 same in every kernel mode, so every op of the compiled program carries its
 stage in its `op_name` metadata (`SearchExecutor.stage_map` reads it back
 for the device trace). Scopes are metadata only: the computation is the
-same with or without them. `ReferenceStep` adds `adc` and `merge`
-sub-scopes under `bang.step`; the fused megakernel cannot have them.
+same with or without them. The per-hop distances of a StepFn whose
+distance source sits outside the kernel (`ReferenceStep`, `StagedStep`,
+`FusedTraverseStep`) run under `bang.distance` inside `bang.step`: the codes
+gather and ADC in the PQ variants, the full-vector gather and L2 in the
+exact one. `ReferenceStep` adds a `merge` sub-scope; the fused megakernel
+(`FusedStep`) cannot split either out of `bang.step`.
 """
 from __future__ import annotations
 
@@ -83,10 +87,11 @@ KERNEL_MODES = ("reference", "staged", "fused")
 
 # Algorithm 2's stages as named scopes: the PQ distance table (stage 1), the
 # neighbour fetch (host exchange or device gather), the bloom filter with the
-# validity mask it consumes, the StepFn (distances, sort, selection, merge),
-# the history update, and the re-rank (stage 3).
+# validity mask it consumes, the StepFn (sort, selection, merge), the per-hop
+# distances inside it (the codes gather and ADC, or the full-vector gather and
+# exact L2), the history update, and the re-rank (stage 3).
 STAGES = ("bang.table", "bang.fetch", "bang.bloom", "bang.step",
-          "bang.history", "bang.rerank")
+          "bang.distance", "bang.history", "bang.rerank")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -253,7 +258,7 @@ class ReferenceStep(StepFn):
         prefetch_fn: "PrefetchFn | None" = None,
     ) -> tuple[Worklist, Array, Array, Array | None]:
         # 3. PQ (or exact) distances for fresh neighbours.
-        with jax.named_scope("adc"):
+        with jax.named_scope("bang.distance"):
             d = self.distance_fn(nbrs, fresh)
         cand_ids = jnp.where(fresh, nbrs, INVALID_ID)
 
@@ -345,7 +350,8 @@ class FusedTraverseStep(StepFn):
     ) -> tuple[Worklist, Array, Array]:
         from repro.kernels.search_step import ops as step_ops
 
-        d = self.distance_fn(nbrs, fresh)
+        with jax.named_scope("bang.distance"):
+            d = self.distance_fn(nbrs, fresh)
         cand_ids = jnp.where(fresh, nbrs, INVALID_ID)
         return step_ops.fused_traverse(wl, d, cand_ids, active, eager=self.eager)
 

@@ -588,23 +588,31 @@ BASE_HIO = HostIOConfig()          # the Base deployment's host-I/O service
 
 
 @pytest.mark.parametrize("mode", ["reference", "staged"])
-@pytest.mark.parametrize("prefetch", [False, True])
-def test_stage_map_holds_every_stage(small_ann_index, mode, prefetch):
-    """Every stage scope reaches the compiled Base program's op metadata; the
-    host callbacks belong to the exchange or the re-rank, the prefetch ones
-    too though they are issued from inside the step."""
+@pytest.mark.parametrize("variant,prefetch", [
+    ("base", False), ("base", True), ("inmem", False), ("exact", False)])
+def test_stage_map_holds_every_stage(small_ann_index, mode, variant, prefetch):
+    """Every stage scope the variant runs reaches its compiled program's op
+    metadata, the per-hop distance (`bang.distance`) apart from the rest of
+    the step; in Base the host callbacks belong to the exchange or the
+    re-rank, the prefetch ones too though they are issued from inside the
+    step. The Exact-distance variant has no table and no re-rank."""
     from repro.core.search import STAGES
 
     data, idx = small_ann_index
-    ex = SearchExecutor.from_index(
-        idx, variant="base", hostio=HostIOConfig(prefetch=prefetch))
+    hostio = HostIOConfig(prefetch=prefetch) if variant == "base" else None
+    ex = SearchExecutor.from_index(idx, variant=variant, hostio=hostio)
     assert ex.stage_map() == {}                  # nothing compiled yet
     ex.search(np.asarray(data[:4]), K, cfg=SearchConfig(t=16,
                                                         kernel_mode=mode))
     stages = ex.stage_map()
-    assert set(stages.values()) == set(STAGES)
+    want = set(STAGES)
+    if variant == "exact":
+        want -= {"bang.table", "bang.rerank"}
+    assert set(stages.values()) == want
+    assert "bang.distance" in want
     callbacks = {stages.get(n) for n in stages if n.startswith("pure_callback")}
-    assert callbacks == {"bang.fetch", "bang.rerank"}
+    assert callbacks == ({"bang.fetch", "bang.rerank"} if variant == "base"
+                         else set())
 
 
 def test_op_stages_from_metadata_fused_computations_and_neighbours():

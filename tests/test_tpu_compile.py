@@ -188,7 +188,9 @@ def test_in_memory_executable_holds_its_index_once(one_chip, no_cache, variant):
     copies the graph, the vectors or the codes, and the temporaries stay
     under 256 MiB (a column-major (N, 64) graph and (N, 96) vectors were
     copied whole on every call: 6.38 GB; the bloom filters held a byte a
-    slot: 409 MB)."""
+    slot: 409 MB). The exact pipeline's per-hop vector gather reads whole
+    1,536 B `PackedRows` lines, four rows to a line, under `bang.distance`:
+    `f32[1024,64,384]` a hop for the (1024, 64, 96) rows it needs."""
     import numpy as np
 
     from repro.core import pq, rows
@@ -213,10 +215,15 @@ def test_in_memory_executable_holds_its_index_once(one_chip, no_cache, variant):
          jax.eval_shape(rows.pack, jax.ShapeDtypeStruct((N_CELL, D_CELL), f32))))
     compiled = ex._compile("cell", B, D_CELL, K_CELL, True,
                            SearchConfig(t=T_CELL), state=state)
-    copies = [line for line in compiled.as_text().splitlines()
+    text = compiled.as_text()
+    copies = [line for line in text.splitlines()
               if " copy(" in line and any(s in line for s in INDEX_SHAPES)]
     assert not copies
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+    if variant == "exact":
+        hop = [line for line in text.splitlines()
+               if " gather(" in line and "= f32[1024,64,384]" in line]
+        assert len(hop) == 1 and "/bang.distance/" in hop[0]
 
 
 @pytest.mark.parametrize("platform", ["tpu", "cpu"])
